@@ -13,11 +13,13 @@ Defaults are CPU-friendly (~10M params, 60 steps, minutes); pass
 """
 import argparse
 import dataclasses
-import sys
+from pathlib import Path
 
-import repro.configs.granite_20b as granite
-from repro.launch import train as train_launcher
 from repro.configs import get_smoke_config
+from repro.configs.granite_20b import HUNDRED_M
+from repro.launch import train
+
+CKPT_DIR = Path(__file__).resolve().parents[1] / "artifacts" / "flight_lm_ckpt"
 
 
 def main():
@@ -28,11 +30,7 @@ def main():
     args = ap.parse_args()
 
     if args.hundred_m:
-        # ~100M decoder: 12L x 768 x 12H, 32k vocab
-        cfg = dataclasses.replace(
-            get_smoke_config("granite-20b"),
-            num_layers=12, d_model=768, num_heads=12, num_kv_heads=4,
-            head_dim=64, d_ff=3072, vocab_size=32_768, remat=True)
+        cfg = HUNDRED_M                 # ~100M: 12L x 768 x 12H, 32k vocab
         steps = args.steps or 300
         batch, seq = 8, 256
     else:
@@ -43,21 +41,12 @@ def main():
         steps = args.steps or 60
         batch, seq = 8, 128
 
-    # register the custom config under a temp name by monkeypatching the
-    # launcher's config lookup (the launcher otherwise uses the registry)
-    import repro.launch.train as T
-    orig = T.get_smoke_config
-    T.get_smoke_config = lambda name: cfg
-    try:
-        argv = ["--arch", "custom-lm", "--smoke", "--steps", str(steps),
-                "--islands", "2", "--local-steps", "5",
-                "--batch", str(batch), "--seq", str(seq),
-                "--ckpt-dir", "/tmp/flight_lm_ckpt", "--ckpt-every", "25"]
-        if args.resume:
-            argv.append("--resume")
-        T.main(argv)
-    finally:
-        T.get_smoke_config = orig
+    argv = ["--steps", str(steps), "--islands", "2", "--local-steps", "5",
+            "--batch", str(batch), "--seq", str(seq),
+            "--ckpt-dir", str(CKPT_DIR), "--ckpt-every", "25"]
+    if args.resume:
+        argv.append("--resume")
+    train.run(train.parse_args(argv), cfg=cfg)
 
 
 if __name__ == "__main__":

@@ -24,6 +24,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from repro.core import aggregation, compression
 
@@ -80,6 +81,24 @@ def _resolve_impl(impl: str) -> str:
     return impl
 
 
+def _quantize_per_island(delta):
+    """The Pallas rowwise quantise of a stacked (P, ..., C) delta.
+
+    GSPMD cannot partition a Mosaic kernel, so under a multi-device mesh
+    (launch/train.island_mesh) the island axis is split by hand: a
+    shard_map over "pod" runs the kernel on each device's own islands,
+    before the mixing contraction moves anything.  Other mesh axes see
+    the delta whole."""
+    from repro.kernels.quant8 import ops as q8ops
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return q8ops.quantize_rowwise(delta)
+    spec = PartitionSpec("pod")
+    # check_vma=False: a pallas_call's outputs carry no varying-axes type
+    return jax.shard_map(q8ops.quantize_rowwise, in_specs=spec,
+                         out_specs=(spec, spec), check_vma=False)(delta)
+
+
 def fl_aggregate_compressed(stacked_params, base_params, mixing, *,
                             mode: str = "q8", k_frac: float = 0.05,
                             impl: str = "auto"):
@@ -102,9 +121,10 @@ def fl_aggregate_compressed(stacked_params, base_params, mixing, *,
     reason: a gather of the survivors would reshard.
 
     impl="auto" quantises through the fused kernels/quant8 Pallas pass on
-    TPU and falls back to the jnp reference (core.compression, same
-    rounding) elsewhere; dequantisation stays jnp so XLA fuses it into
-    the mixing contraction."""
+    TPU (per island, see _quantize_per_island) and takes the jnp
+    reference (core.compression, same rounding) on other backends;
+    dequantisation stays jnp so XLA fuses it into the mixing
+    contraction."""
     if mode == "none":
         return fl_aggregate(stacked_params, mixing)
     if mode not in compression.MODES:
@@ -120,8 +140,7 @@ def fl_aggregate_compressed(stacked_params, base_params, mixing, *,
             delta = jnp.where(mask, delta, 0.0)
         if mode in ("q8", "q8_topk"):
             if use_pallas:
-                from repro.kernels.quant8 import ops as q8ops
-                q, scale = q8ops.quantize_rowwise(delta)
+                q, scale = _quantize_per_island(delta)
             else:
                 q, scale = compression.quantize_rowwise(delta)
             delta = q.astype(jnp.float32) * scale

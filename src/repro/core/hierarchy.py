@@ -198,7 +198,8 @@ def hierarchical_sync_aggregate(stacked_params, weights: Sequence[float],
                                 cell_of: Sequence[int], *,
                                 compress: str = "none",
                                 base_params=None,
-                                k_frac: float = 0.05):
+                                k_frac: float = 0.05,
+                                impl: str = "auto"):
     """Two `fl_aggregate` hops (edge then cloud) over the island axis.
 
     cloud_mixing_matrix @ edge_mixing_matrix == flat_mixing_matrix, so this
@@ -212,7 +213,8 @@ def hierarchical_sync_aggregate(stacked_params, weights: Sequence[float],
     cell->cloud hop spans cells), matching the fog-tier byte budget the
     paper's transmission-cost analysis targets.  Equals the flat
     compressed exchange up to one extra quantisation of the fog-stage
-    deltas (bounded by the per-row scale; see tests/test_hierarchy.py)."""
+    deltas (bounded by the per-row scale; see tests/test_hierarchy.py).
+    `impl` picks the quantiser as in fl_aggregate_compressed."""
     from repro.core.federated import fl_aggregate, fl_aggregate_compressed
     edge_M = jnp.asarray(edge_mixing_matrix(weights, cell_of), jnp.float32)
     cloud_M = jnp.asarray(cloud_mixing_matrix(weights, cell_of), jnp.float32)
@@ -223,9 +225,9 @@ def hierarchical_sync_aggregate(stacked_params, weights: Sequence[float],
         raise ValueError("compressed hierarchical exchange needs the "
                          "shared last-sync base_params")
     fog = fl_aggregate_compressed(stacked_params, base_params, edge_M,
-                                  mode=compress, k_frac=k_frac)
+                                  mode=compress, k_frac=k_frac, impl=impl)
     return fl_aggregate_compressed(fog, base_params, cloud_M,
-                                   mode=compress, k_frac=k_frac)
+                                   mode=compress, k_frac=k_frac, impl=impl)
 
 
 def hierarchical_async_aggregate(stacked_params, alphas: Sequence[float],

@@ -57,23 +57,15 @@ def collective_bytes(text: str) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# XLA compiled-module analyses (version tolerant)
+# XLA compiled-module analyses
 # ---------------------------------------------------------------------------
 
 def cost_analysis_terms(compiled) -> tuple[float, float]:
     """(flops, bytes_accessed) from compiled.cost_analysis(); 0.0 when the
     backend does not report a term.  NOTE: XLA counts loop bodies ONCE --
     use dist.hlo_cost for trip-count-aware totals."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:
-        return 0.0, 0.0
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    if not isinstance(ca, dict):
-        return 0.0, 0.0
-    return (float(ca.get("flops", 0.0)),
-            float(ca.get("bytes accessed", ca.get("bytes_accessed", 0.0))))
+    ca = compiled.cost_analysis()
+    return float(ca.get("flops", 0.0)), float(ca.get("bytes accessed", 0.0))
 
 
 _MEM_FIELDS = ("argument_size_in_bytes", "output_size_in_bytes",
@@ -82,19 +74,9 @@ _MEM_FIELDS = ("argument_size_in_bytes", "output_size_in_bytes",
 
 
 def memory_analysis_dict(compiled) -> dict:
-    """compiled.memory_analysis() flattened to a plain dict (or {})."""
-    try:
-        ma = compiled.memory_analysis()
-    except Exception:
-        return {}
-    if ma is None:
-        return {}
-    out = {}
-    for f in _MEM_FIELDS:
-        v = getattr(ma, f, None)
-        if v is not None:
-            out[f] = int(v)
-    return out
+    """compiled.memory_analysis() flattened to a plain dict."""
+    ma = compiled.memory_analysis()
+    return {f: int(getattr(ma, f)) for f in _MEM_FIELDS}
 
 
 # ---------------------------------------------------------------------------

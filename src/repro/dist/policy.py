@@ -61,11 +61,29 @@ from repro.dist.sharding import (SERVE_LAYOUTS, logical_to_mesh_spec,
                                  serve_layout_rules)
 
 #: Per-device HBM of the modeled chip (v5e-class, 16 GB; see the hardware
-#: constants in dist/hlo_analysis.py).
+#: constants in dist/hlo_analysis.py): the budget of CPU AOT dry-runs,
+#: which compile for a chip that is not attached.
 DEVICE_HBM_BYTES = 16e9
 
 #: Fraction of DEVICE_HBM_BYTES a layout may use before it is infeasible.
 DEFAULT_MARGIN = 0.9
+
+
+def device_hbm_bytes(device) -> float:
+    """The HBM budget to lay a model out on `device`.
+
+    On the CPU backend (AOT dry-runs, tests) the modeled v5e constant; on
+    an attached accelerator the runtime's own limit,
+    `memory_stats()["bytes_limit"]`.  An accelerator that does not report
+    one cannot be sized, and that is an error, never a default."""
+    if device.platform == "cpu":
+        return DEVICE_HBM_BYTES
+    stats = device.memory_stats() or {}
+    if "bytes_limit" not in stats:
+        raise ValueError(f"cannot size the memory of {device.platform} "
+                         f"device_kind={device.device_kind!r}: "
+                         "memory_stats() reports no bytes_limit")
+    return float(stats["bytes_limit"])
 
 
 # ---------------------------------------------------------------------------

@@ -23,7 +23,7 @@ Three rule sets ship here:
                    replicated, everything tensor-parallel goes to "model".
 
 `constrain(x, logical_axes)` applies `with_sharding_constraint` against the
-AMBIENT mesh (the `with mesh:` context the caller lowered under) and the
+AMBIENT mesh (the `jax.set_mesh` context the caller traced under) and the
 ambient rules (`use_rules`).  With no ambient mesh it is a no-op, so model
 code runs unchanged in single-device CPU tests.
 """
@@ -288,21 +288,9 @@ def use_rules(rules: RuleSet):
 
 
 def _ambient_mesh():
-    """The mesh of the enclosing `with mesh:` / `use_mesh` context."""
-    try:                                    # classic thread-resources mesh
-        from jax._src import mesh as _mesh_lib
-        m = _mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    try:                                    # newer explicit-mesh API
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+    """The mesh of the enclosing `jax.set_mesh(mesh)` context, or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def constrain(x, logical_axes):
@@ -317,15 +305,6 @@ def constrain(x, logical_axes):
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-# ---------------------------------------------------------------------------
-# Version compat
-# ---------------------------------------------------------------------------
-
 def abstract_mesh(axis_sizes, axis_names):
-    """AbstractMesh across jax versions: (sizes, names) vs ((name, size),)."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_sizes),
-                                         tuple(axis_names))
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(axis_names, axis_sizes)))
+    """A device-free mesh of this shape, for analytic layout checks."""
+    return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 DEFAULT_BT = 256
 DEFAULT_BD = 512
 
@@ -73,7 +71,7 @@ def linrec_btd(a, b, *, bt: int = DEFAULT_BT, bd: int = DEFAULT_BD,
         out_specs=pl.BlockSpec((1, bt, bd), lambda ib, jd, it: (ib, it, jd)),
         out_shape=jax.ShapeDtypeStruct((B, T, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

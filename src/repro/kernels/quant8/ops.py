@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from repro.kernels.quant8.kernel import (BLOCK, dequantize_blocked,
                                          quantize_blocked)
 
-LANES = 128  # TPU lane width: rowwise pads the channel dim up to this
-
 
 def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
@@ -35,8 +33,8 @@ def quantize(x, *, block: int = BLOCK, impl: str = "auto"):
     pad = (-flat.shape[0]) % block
     if pad:
         flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-    xb = flat.reshape(-1, block)
-    q, s = quantize_blocked(xb, interpret=_use_interpret())
+    q, s = quantize_blocked(flat.reshape(-1, block),
+                            interpret=_use_interpret())
     return q, s[:, 0]
 
 
@@ -48,30 +46,22 @@ def dequantize(q, scales, shape, *, out_dtype=jnp.float32,
     flat = dequantize_blocked(q, scales.reshape(-1, 1),
                               out_dtype=out_dtype,
                               interpret=_use_interpret()).reshape(-1)
-    n = 1
-    for d in shape:
-        n *= d
-    return flat[:n].reshape(shape)
+    return flat[:math.prod(shape)].reshape(shape)
 
 
 def quantize_rowwise(x, *, impl: str = "auto"):
     """x: (..., C) -> (q int8 SAME shape, fp32 scales (..., 1)).
 
-    The leading dims collapse to kernel rows and C pads up to a lane
-    multiple (zero pad never changes a row's absmax), so the result
-    matches core.compression.quantize_rowwise exactly while the absmax +
-    scale + round + cast run as one fused VMEM pass."""
+    The leading dims collapse to kernel rows; the result matches
+    core.compression.quantize_rowwise exactly while the absmax + scale +
+    round + cast run fused in VMEM."""
     if impl == "ref":
         from repro.core.compression import quantize_rowwise as ref
         return ref(x)
     C = x.shape[-1]
-    rows = math.prod(x.shape[:-1]) if x.ndim > 1 else 1
-    x2 = x.astype(jnp.float32).reshape(rows, C)
-    padc = (-C) % LANES
-    if padc:
-        x2 = jnp.pad(x2, ((0, 0), (0, padc)))
+    x2 = x.astype(jnp.float32).reshape(-1, C)
     q, s = quantize_blocked(x2, interpret=_use_interpret())
-    return q[:, :C].reshape(x.shape), s.reshape(x.shape[:-1] + (1,))
+    return q.reshape(x.shape), s.reshape(x.shape[:-1] + (1,))
 
 
 def dequantize_rowwise(q, scale, *, out_dtype=jnp.float32,
@@ -81,12 +71,7 @@ def dequantize_rowwise(q, scale, *, out_dtype=jnp.float32,
         from repro.core.compression import dequantize_rowwise as ref
         return ref(q, scale, out_dtype=out_dtype)
     C = q.shape[-1]
-    rows = math.prod(q.shape[:-1]) if q.ndim > 1 else 1
-    q2 = q.reshape(rows, C)
-    padc = (-C) % LANES
-    if padc:
-        q2 = jnp.pad(q2, ((0, 0), (0, padc)))
-    out = dequantize_blocked(q2, scale.reshape(rows, 1),
+    out = dequantize_blocked(q.reshape(-1, C), scale.reshape(-1, 1),
                              out_dtype=out_dtype,
                              interpret=_use_interpret())
-    return out[:, :C].reshape(q.shape)
+    return out.reshape(q.shape)

@@ -1,7 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS", ""))
-# ^ MUST precede any jax import: jax locks the device count on first init.
+# A CPU AOT tool: pin the CPU platform for this process and the per-cell
+# children it starts, so none of them takes an attached TPU.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ both MUST precede any jax import: jax locks the device count and the
+# platform on first init.
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -114,7 +118,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
         t0 = time.time()
         jitted = jax.jit(fn, in_shardings=in_shardings,
                          donate_argnums=donate)
-        with use_rules(rules), mesh:  # ambient mesh so constrain() resolves
+        with use_rules(rules), jax.set_mesh(mesh):  # constrain() resolves
             lowered = jitted.lower(*args)
         t1 = time.time()
         compiled = lowered.compile()

@@ -12,17 +12,12 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types across jax versions (axis_types=
-    only exists on newer jax, where Auto is the default anyway)."""
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(at.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes, *, devices=None):
+    """jax.make_mesh with Auto axes (its default is Explicit): sharding is
+    left to GSPMD plus the constraints model code places."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def production_mesh_spec(*, multi_pod: bool = False):

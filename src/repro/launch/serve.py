@@ -5,14 +5,19 @@
 
 Weight layout (stationary / hybrid / fsdp) is chosen by the memory-aware
 policy in repro.dist.policy (`--layout auto`, the default), or forced
-with `--layout <name>`.  The chosen RuleSet is ambient while the steps
-trace, so `constrain()` calls in model code resolve against it; at smoke
-scale (1 host device) every layout degenerates to replicated and the
-decision is only reported.
+with `--layout <name>`, against the HBM the attached device reports
+(`policy.device_hbm_bytes`).  The chosen RuleSet is ambient while the
+steps trace, so `constrain()` calls in model code resolve against it; on
+one device every layout degenerates to replicated and the decision is
+only reported.
+
+`load`, `serve_paged` and `serve_contiguous` are the pieces `main` is made
+of; chip_smoke.py drives the same ones.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -22,6 +27,7 @@ import numpy as np
 from repro.configs import get_config, get_smoke_config
 from repro.dist import policy as dist_policy
 from repro.dist.sharding import SERVE_LAYOUTS, use_rules
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.models import build_model
@@ -34,9 +40,10 @@ def pick_layout(model, mesh, *, batch: int, seq_len: int,
     analytic product decision for "auto"/"auto", else the named layout
     and/or CacheSpec (the full candidate table is still computed so the
     caller can log headroom)."""
-    import dataclasses
     shape = ShapeConfig("serve", "decode", seq_len, batch)
-    decision = dist_policy.analytic_serve_decision(model, shape, mesh)
+    decision = dist_policy.analytic_serve_decision(
+        model, shape, mesh,
+        budget_bytes=dist_policy.device_hbm_bytes(mesh.devices.flat[0]))
     if cache != "auto" and model.supports_cache_spec:
         from repro.models.cache import CacheSpec
         cache = CacheSpec.parse(cache).name
@@ -67,6 +74,70 @@ def pick_layout(model, mesh, *, batch: int, seq_len: int,
     return decision
 
 
+def load(cfg, *, batch: int, seq_len: int, layout: str = "auto",
+         cache: str = "auto", seed: int = 0, params=None):
+    """Build the model for `cfg`, init its params from `seed` (or take
+    `params`, which no cache spec changes) and pick the serve (layout,
+    cache spec).  Returns (model, params, decision); the model carries
+    the chosen cache spec."""
+    model = build_model(cfg)
+    if params is None:
+        params = model.init(jax.random.key(seed))
+    decision = pick_layout(model, make_host_mesh(), batch=batch,
+                           seq_len=seq_len, layout=layout, cache=cache)
+    if (model.supports_cache_spec and decision.cache_spec
+            and decision.cache_spec != cfg.cache_spec):
+        model = build_model(dataclasses.replace(
+            cfg, cache_spec=decision.cache_spec))
+    print(f"[serve] layout={decision.layout}"
+          + (f" cache={decision.cache_spec}" if decision.cache_spec else "")
+          + f" (peak {decision.chosen.hbm_bytes/1e9:.2f} GB/dev, "
+          f"headroom {decision.headroom_bytes()/1e9:.2f} GB) "
+          f"-- {decision.reason}")
+    return model, params, decision
+
+
+def serve_paged(model, params, requests, *, max_batch: int,
+                block_size: int, num_blocks: int, layout: str):
+    """Serve `requests` through PagedServeLoop; at most `max_batch` run at
+    once, so later ones join mid-flight.  Returns (finished requests,
+    loop, wall seconds)."""
+    from repro.launch.serve_loop import PagedServeLoop
+    loop = PagedServeLoop(model, params, max_batch=max_batch,
+                          num_blocks=num_blocks, block_size=block_size,
+                          chunk=max(block_size * 4, 32), layout=layout)
+    for r in requests:
+        loop.submit(r)
+    t0 = time.time()
+    done = loop.run_until_drained()
+    return done, loop, time.time() - t0
+
+
+def serve_contiguous(model, params, batch, *, gen: int, rules):
+    """Prefill `batch` in one shot, then decode `gen - 1` greedy steps
+    with the contiguous cache.  Returns ((B, gen) tokens, prefill
+    seconds, decode seconds)."""
+    prefill = jax.jit(make_prefill_step(model))
+    decode = jax.jit(make_decode_step(model))
+    B, T = batch["tokens"].shape
+    # the rules must be ambient while the steps TRACE (first call)
+    with use_rules(rules):
+        t0 = time.time()
+        nxt, cache = prefill(params, batch)
+        jax.block_until_ready(nxt)
+        t_prefill = time.time() - t0
+        out = [np.asarray(nxt)]
+        t0 = time.time()
+        for i in range(gen - 1):
+            nxt, cache = decode(params, {
+                "tokens": nxt[:, None].astype(jnp.int32),
+                "positions": jnp.full((B, 1), T + i, jnp.int32)}, cache)
+            out.append(np.asarray(nxt))
+        jax.block_until_ready(nxt)
+        t_dec = time.time() - t0
+    return np.stack(out, axis=1), t_prefill, t_dec
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="falcon-mamba-7b")
@@ -91,43 +162,24 @@ def main(argv=None):
                     help="KV block pool size (default: sized so the pool "
                          "covers batch x (prompt+gen))")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    model = build_model(cfg)
-    params = model.init(jax.random.key(args.seed))
-    mesh = make_host_mesh()
-    decision = pick_layout(model, mesh, batch=args.batch,
-                           seq_len=args.prompt_len + args.gen,
-                           layout=args.layout, cache=args.cache)
-    if (model.supports_cache_spec and decision.cache_spec
-            and decision.cache_spec != cfg.cache_spec):
-        import dataclasses as _dc
-        # params are spec-independent: only the cache tree changes shape
-        cfg = _dc.replace(cfg, cache_spec=decision.cache_spec)
-        model = build_model(cfg)
-    print(f"[serve] layout={decision.layout}"
-          + (f" cache={decision.cache_spec}" if decision.cache_spec else "")
-          + f" (peak {decision.chosen.hbm_bytes/1e9:.2f} GB/dev, "
-          f"headroom {decision.headroom_bytes()/1e9:.2f} GB) "
-          f"-- {decision.reason}")
+    B, T = args.batch, args.prompt_len
+    model, params, decision = load(cfg, batch=B, seq_len=T + args.gen,
+                                   layout=args.layout, cache=args.cache,
+                                   seed=args.seed)
+    rng = np.random.default_rng(args.seed)
     if args.paged:
-        from repro.launch.serve_loop import PagedServeLoop, Request
-        rng = np.random.default_rng(args.seed)
-        B, T = args.batch, args.prompt_len
-        per_seq = T + args.gen
-        nb = args.num_blocks or -(-(B * per_seq + args.block_size)
+        from repro.launch.serve_loop import Request
+        nb = args.num_blocks or -(-(B * (T + args.gen) + args.block_size)
                                   // args.block_size)
-        loop = PagedServeLoop(model, params, max_batch=B, num_blocks=nb,
-                              block_size=args.block_size,
-                              chunk=max(args.block_size * 4, 32),
-                              layout=decision.layout)
-        for i in range(2 * B):   # oversubscribe: requests join mid-flight
-            loop.submit(Request(
-                rid=i, prompt=rng.integers(0, cfg.vocab_size, T)
-                .astype(np.int32), max_new=args.gen))
-        t0 = time.time()
-        done = loop.run_until_drained()
-        wall = time.time() - t0
+        requests = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, T)
+                            .astype(np.int32), max_new=args.gen)
+                    for i in range(2 * B)]   # oversubscribe: mid-flight joins
+        done, loop, wall = serve_paged(model, params, requests, max_batch=B,
+                                       block_size=args.block_size,
+                                       num_blocks=nb, layout=decision.layout)
         toks = sum(len(r.out) for r in done)
         print(f"[serve] paged loop: {len(done)} reqs, {toks} tokens in "
               f"{wall*1e3:.1f}ms ({toks/max(wall,1e-9):.0f} tok/s); "
@@ -138,11 +190,6 @@ def main(argv=None):
               f"{[r.out[:12] for r in done[:4]]}")
         return
 
-    prefill = jax.jit(make_prefill_step(model))
-    decode = jax.jit(make_decode_step(model))
-
-    rng = np.random.default_rng(args.seed)
-    B, T = args.batch, args.prompt_len
     batch = {"tokens": jnp.asarray(
         rng.integers(0, cfg.vocab_size, (B, T)), jnp.int32)}
     if cfg.frontend == "vision_stub":
@@ -151,28 +198,11 @@ def main(argv=None):
     if cfg.is_encdec:
         batch["frames"] = jnp.asarray(
             rng.normal(size=(B, T, cfg.d_model)), jnp.bfloat16)
-
-    # rules AND mesh must be ambient while the steps TRACE (first call):
-    # constrain() in model code no-ops without an ambient mesh, so the
-    # chosen layout only binds under both contexts
-    with use_rules(decision.rules), mesh:
-        t0 = time.time()
-        nxt, cache = prefill(params, batch)
-        jax.block_until_ready(nxt)
-        t_prefill = time.time() - t0
-        print(f"[serve] prefill {B}x{T}: {t_prefill*1e3:.1f}ms "
-              f"({B*T/t_prefill:.0f} tok/s)")
-
-        out = [np.asarray(nxt)]
-        t0 = time.time()
-        for i in range(args.gen - 1):
-            nxt, cache = decode(params, {
-                "tokens": nxt[:, None].astype(jnp.int32),
-                "positions": jnp.full((B, 1), T + i, jnp.int32)}, cache)
-            out.append(np.asarray(nxt))
-        jax.block_until_ready(nxt)
-        t_dec = time.time() - t0
-    toks = np.stack(out, axis=1)
+    toks, t_prefill, t_dec = serve_contiguous(model, params, batch,
+                                              gen=args.gen,
+                                              rules=decision.rules)
+    print(f"[serve] prefill {B}x{T}: {t_prefill*1e3:.1f}ms "
+          f"({B*T/t_prefill:.0f} tok/s)")
     print(f"[serve] decode {args.gen} steps: {t_dec*1e3:.1f}ms "
           f"({B*(args.gen-1)/max(t_dec,1e-9):.0f} tok/s)")
     print(f"[serve] sample generations (first 12 ids): {toks[:, :12].tolist()}")

@@ -21,8 +21,8 @@ Two cache disciplines behind one Request/submit/tick API:
 
 CPU-runnable at smoke scale; the same loops drive TPU serving, with the
 weight layout (stationary / hybrid / fsdp) picked per model by the
-memory-aware policy in repro.dist.policy -- pass `mesh=` to get an
-analytic decision, or `layout=` to force one.
+memory-aware policy in repro.dist.policy (launch/serve.py passes its
+decision as `layout=`).
 """
 from __future__ import annotations
 
@@ -46,24 +46,17 @@ class Request:
 
 
 class _ServeBase:
-    """Layout/mesh plumbing + queue discipline shared by both loops."""
+    """Layout plumbing + queue discipline shared by both loops."""
 
-    def __init__(self, model, params, *, max_batch: int, mesh=None,
-                 layout: str = "auto", shape=None):
+    def __init__(self, model, params, *, max_batch: int,
+                 layout: str = "auto"):
         self.model = model
         self.params = params
         self.B = max_batch
-        self.layout_decision = None
         self.rules = None
-        self.mesh = mesh
         if layout != "auto":
             from repro.dist.sharding import serve_layout_rules
             self.rules = serve_layout_rules(layout)
-        elif mesh is not None:
-            from repro.dist import policy as dist_policy
-            self.layout_decision = dist_policy.analytic_serve_decision(
-                model, shape, mesh)
-            self.rules = self.layout_decision.rules
         self.live: dict[int, Request] = {}   # slot -> request
         self.free = list(range(max_batch))
         self.queue: list[Request] = []
@@ -75,17 +68,13 @@ class _ServeBase:
         self._next = jnp.zeros((max_batch,), jnp.int32)
 
     def _rules_ctx(self):
-        """Make the chosen layout's rules AND the mesh ambient while a
-        step traces: constrain() in model code no-ops without an ambient
-        mesh, so the layout only binds under both (plain nullcontext for
-        CPU smoke tests with neither)."""
-        stack = contextlib.ExitStack()
-        if self.rules is not None:
-            from repro.dist.sharding import use_rules
-            stack.enter_context(use_rules(self.rules))
-        if self.mesh is not None:
-            stack.enter_context(self.mesh)
-        return stack
+        """Make the chosen layout's rules ambient while a step traces.
+        constrain() in model code binds them only under an ambient mesh
+        (`jax.set_mesh` around the loop); without one it is a no-op."""
+        if self.rules is None:
+            return contextlib.nullcontext()
+        from repro.dist.sharding import use_rules
+        return use_rules(self.rules)
 
     def submit(self, req: Request):
         self.queue.append(req)
@@ -103,19 +92,12 @@ class ServeLoop(_ServeBase):
     """Contiguous per-slot cache (see module docstring)."""
 
     def __init__(self, model, params, *, max_batch: int = 4,
-                 max_len: int = 512, mesh=None, layout: str = "auto",
+                 max_len: int = 512, layout: str = "auto",
                  cache_spec: str | None = None):
-        from repro.models.config import ShapeConfig
-        super().__init__(model, params, max_batch=max_batch, mesh=mesh,
-                         layout=layout,
-                         shape=ShapeConfig("serve", "decode", max_len,
-                                           max_batch))
+        super().__init__(model, params, max_batch=max_batch, layout=layout)
         # cache_spec: "layout[:shards]/dtype" (models/cache.py) forces the
-        # KV-cache layout; None defers to the layout policy's product
-        # decision (when mesh= was given), else the config's own spec.
+        # KV-cache layout; None keeps the config's own spec.
         spec = cache_spec
-        if spec is None and self.layout_decision is not None:
-            spec = self.layout_decision.cache_spec or None
         if spec and model.supports_cache_spec \
                 and spec != model.cfg.cache_spec:
             from repro.models import build_model
@@ -188,7 +170,10 @@ class ServeLoop(_ServeBase):
         self._admit()
         if not self.live:
             return []
-        positions = jnp.asarray(self.lengths.reshape(self.B, 1), jnp.int32)
+        # copy: on CPU jnp.asarray may alias the numpy buffer, which the
+        # loop below mutates while the dispatched decode still reads it
+        positions = jnp.array(self.lengths.reshape(self.B, 1), jnp.int32,
+                              copy=True)
         nxt, self.cache = self._decode(
             self.params, self.cache, self._next[:, None], positions)
         self._next = nxt.astype(jnp.int32)
@@ -220,17 +205,12 @@ class PagedServeLoop(_ServeBase):
 
     def __init__(self, model, params, *, max_batch: int = 4,
                  num_blocks: int = 64, block_size: int = 16,
-                 chunk: int = 64, mesh=None, layout: str = "auto"):
-        from repro.models.config import ShapeConfig
+                 chunk: int = 64, layout: str = "auto"):
         assert model.supports_paged_cache, (
             f"{model.cfg.name}: paged serving needs a growing KV cache "
             f"(family={model.cfg.family}); use ServeLoop")
         assert chunk % block_size == 0, "chunk must be block-aligned"
-        super().__init__(model, params, max_batch=max_batch, mesh=mesh,
-                         layout=layout,
-                         shape=ShapeConfig("serve", "decode",
-                                           num_blocks * block_size,
-                                           max_batch))
+        super().__init__(model, params, max_batch=max_batch, layout=layout)
         self.alloc = BlockAllocator(num_blocks, block_size)
         self.bs = block_size
         self.nbmax = num_blocks            # a table can never exceed the pool
@@ -321,7 +301,7 @@ class PagedServeLoop(_ServeBase):
         (positions -1 => writes dropped, logits taken at the last valid
         row).  `start` skips positions covered by shared prefix blocks --
         their K/V is already resident."""
-        bt_row = jnp.asarray(self.bt[slot: slot + 1])
+        bt_row = jnp.array(self.bt[slot: slot + 1], copy=True)  # see tick
         pos = start
         nxt = None
         while pos < T:
@@ -392,7 +372,7 @@ class PagedServeLoop(_ServeBase):
         for slot in self.live:
             positions[slot] = self.lengths[slot]
         nxt, self.pages = self._decode(
-            self.params, self.pages, jnp.asarray(self.bt),
+            self.params, self.pages, jnp.array(self.bt, copy=True),
             self._next[:, None], jnp.asarray(positions[:, None]))
         self._next = nxt.astype(jnp.int32)
         finished = []

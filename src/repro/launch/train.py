@@ -1,22 +1,25 @@
 """Federated training launcher (Tier B semantics, any scale).
 
-On CPU this runs the REAL production path at smoke scale: P virtual islands
-(vmapped, leading island axis), E local steps between weight exchanges, the
-exchange as one mixing collective, straggler-driven selection, int8
-compression, checkpoints + resume.  On a TPU pod the same script runs with
---mesh production (the pod axis becomes the island axis).
+P islands (vmapped, leading island axis), E local steps between weight
+exchanges, the exchange as one mixing collective, straggler-driven
+selection, int8 compression, checkpoints + resume.  The island axis is
+placed on the `pod` axis of a mesh over the attached devices
+(`island_mesh`): with 4 chips and 4 islands each island owns a chip; with
+one device (the CPU tests) every island is stacked on it.
 
   PYTHONPATH=src python -m repro.launch.train --arch granite-20b --smoke \
-      --steps 60 --islands 2 --local-steps 5 --ckpt-dir /tmp/flight_ckpt
+      --steps 60 --islands 2 --local-steps 5 --ckpt-dir ckpt/flight
 """
 from __future__ import annotations
 
 import argparse
+import math
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, get_smoke_config
@@ -24,12 +27,25 @@ from repro.core import aggregation
 from repro.core import faults as faults_mod
 from repro.core import federated as fed
 from repro.data.synthetic import batch_token_stream, make_token_stream
+from repro.dist.sharding import ISLAND_RULES, use_rules
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_fl_aggregate, make_fl_train_step
 from repro.models import build_model
 from repro.optim import adamw, cosine_warmup
 
 
-def main(argv=None):
+def island_mesh(n_islands: int, devices=None):
+    """(pod, data, model) mesh over `devices` (default: all attached) with
+    as many islands per pod slot as divide evenly: pod = gcd(islands,
+    devices), the remaining devices go to "data"."""
+    devices = list(devices if devices is not None else jax.devices())
+    pod = math.gcd(max(n_islands, 1), len(devices))
+    return make_mesh((pod, len(devices) // pod, 1), ("pod", "data", "model"),
+                     devices=devices)
+
+
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-20b")
     ap.add_argument("--smoke", action="store_true", default=True)
@@ -72,10 +88,29 @@ def main(argv=None):
                          "Byzantine-robust fold of the island models")
     ap.add_argument("--trim-frac", type=float, default=0.2)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+
+def main(argv=None):
+    enable_compile_cache()
+    run(parse_args(argv))
+
+
+def run(args, *, cfg=None, devices=None) -> dict:
+    """Train as `args` say; returns {"losses": per-step mean loss,
+    "params": final params}.  `cfg` overrides the --arch lookup and
+    `devices` the attached devices the island mesh is built from."""
+    if cfg is None:
+        cfg = get_smoke_config(args.arch) if args.smoke else \
+            get_config(args.arch)
     model = build_model(cfg)
+    mesh = island_mesh(args.islands, devices)
+    with use_rules(ISLAND_RULES), jax.set_mesh(mesh):
+        return _train(args, model, mesh)
+
+
+def _train(args, model, mesh) -> dict:
+    cfg = model.cfg
     P = args.islands
     compress = args.compress.replace("-", "_")
     opt = adamw(cosine_warmup(args.lr, 10, args.steps))
@@ -87,9 +122,12 @@ def main(argv=None):
 
     params = model.init(jax.random.key(args.seed))
     opt_state = opt.init(params)
+    islands = NamedSharding(mesh, PartitionSpec("pod") if P > 1
+                            else PartitionSpec())
     if P > 1:
         params = fed.stack_islands(params, P)
         opt_state = fed.stack_islands(opt_state, P)
+    params, opt_state = jax.device_put((params, opt_state), islands)
 
     plan = None
     if args.byzantine > 0 and P > 1:
@@ -105,8 +143,7 @@ def main(argv=None):
     if mgr and args.resume and mgr.latest_step() is not None:
         start, params, opt_state, extra = mgr.restore(
             params_like=params, opt_state_like=opt_state)
-        params = jax.tree.map(jnp.asarray, params)
-        opt_state = jax.tree.map(jnp.asarray, opt_state)
+        params, opt_state = jax.device_put((params, opt_state), islands)
         print(f"[train] resumed from step {start}")
 
     streams = [make_token_stream(cfg.vocab_size, 400_000, seed=args.seed + i)
@@ -119,11 +156,10 @@ def main(argv=None):
             x, y = batch_token_stream(streams[i], args.batch, args.seq, s)
             xs.append(x)
             ys.append(y)
-        b = {"tokens": jnp.asarray(np.stack(xs)),
-             "labels": jnp.asarray(np.stack(ys))}
+        b = {"tokens": np.stack(xs), "labels": np.stack(ys)}
         if P == 1:
             b = jax.tree.map(lambda v: v[0], b)
-        return b
+        return jax.device_put(b, islands)
 
     def dispatch_exchange(cur_params, sel):
         """Issue this round's mixing collective (async under jax dispatch).
@@ -213,6 +249,7 @@ def main(argv=None):
         return out, ok
 
     pending = None   # (mixed, snapshot) while an overlapped exchange flies
+    losses = []
     for s in range(start, args.steps):
         t0 = time.time()
         params, opt_state, metrics = step(params, opt_state, batch_at(s))
@@ -229,7 +266,8 @@ def main(argv=None):
         jax.block_until_ready(metrics["loss"])
         dt = time.time() - t0
         clock.observe(np.full(P, dt))  # per-island step times (uniform on CPU)
-        loss = np.asarray(metrics["loss"]).mean()
+        loss = float(np.asarray(metrics["loss"]).mean())
+        losses.append(loss)
         if (s + 1) % args.local_steps == 0 and P > 1:
             sel = clock.selection(args.straggler_slack)
             ex_in, ok = exchange_input(params, (s + 1) // args.local_steps)
@@ -252,6 +290,7 @@ def main(argv=None):
                      extra={"arch": args.arch, "islands": P})
             print(f"[train] checkpoint @ {s+1}")
     print("[train] done")
+    return {"losses": losses, "params": params}
 
 
 if __name__ == "__main__":

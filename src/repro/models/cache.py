@@ -27,8 +27,8 @@ A CacheSpec is `layout[:shards]/dtype`:
   dtype   "bf16", or "int8" -- rowwise-quantised K/V (kernels/quant8,
           per (token, head) fp32 scales over head_dim) with dequant
           fused into the attention reads by XLA.  Halves cache HBM
-          (+ ~3%% scale overhead) at a <=1e-2 logit cost
-          (tests/test_cache_spec.py pins the parity).
+          (+ ~3%% scale overhead) at a logit cost bounded by
+          INT8_LOGIT_BOUND (tests/test_cache_spec.py pins the parity).
 
 The spec is owned by the model config (`ModelConfig.cache_spec`, default
 "auto" == "head/bf16" == the historical behaviour); the serve policy
@@ -218,6 +218,16 @@ def cache_bytes(cfg, batch: int, seq_len: int,
 # ---------------------------------------------------------------------------
 # Quantised read/write (rowwise int8 over head_dim; kernels/quant8)
 # ---------------------------------------------------------------------------
+
+#: Scale-relative max logit error (max|d| / max|ref|) of teacher-forced
+#: decode allowed for an int8 cache against bf16.  Set from readings, not
+#: derived: int8 measured 0.0102 at smoke depth (2 layers, CPU) and 0.0165
+#: at qwen1.5-4b's 40 layers (TPU v5e); the bf16 cache rounded rowwise to
+#: int4 levels, the control that must fail it, measured 0.046 at smoke
+#: depth (tests/test_cache_spec.py) and is read at full depth by
+#: chip_smoke.py.
+INT8_LOGIT_BOUND = 0.03
+
 
 def _q8_impl() -> str:
     # the Pallas rowwise kernel on TPU; the jnp reference elsewhere

@@ -64,7 +64,7 @@ def test_elastic_restore_new_sharding(tmp_path):
     mesh is live."""
     mgr = CheckpointManager(tmp_path)
     mgr.save(1, params=tree(2.0))
-    from repro.launch.mesh import make_mesh  # version-compat Auto axes
+    from repro.launch.mesh import make_mesh  # Auto axes
     mesh = make_mesh((1,), ("data",))
     sh = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
     shardings = jax.tree.map(lambda _: sh, tree())

@@ -69,8 +69,7 @@ def test_xla_cost_analysis_undercounts_scans():
     x = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     w = jax.ShapeDtypeStruct((16, 128, 128), jnp.float32)
     compiled = jax.jit(f_scan).lower(x, w).compile()
-    # cost_analysis() returns dict or [dict] depending on the jax version;
-    # cost_analysis_terms normalises that (and is what dryrun records).
+    # cost_analysis_terms is what dryrun records.
     from repro.dist.hlo_analysis import cost_analysis_terms
     xla_flops, _ = cost_analysis_terms(compiled)
     assert xla_flops > 0  # extraction worked; keeps the 10x check meaningful

@@ -93,23 +93,33 @@ def test_quant8_zero_delta_scale_clamp(impl):
 
 
 @pytest.mark.parametrize("impl", ["auto", "ref"])
-@pytest.mark.parametrize("shape", [(5, 7), (3, 300), (1000,), (2, 3, 130)])
+@pytest.mark.parametrize("shape", [
+    (5, 7), (3, 300), (1000,), (2, 3, 130),
+    (600, 1000),      # several row tiles, the last one partial
+    (3, 5000),        # rows wider than one column tile: running absmax
+    (300, 2100),      # column tiles x partial row tiles, masked tail
+    (2, 151936),      # qwen1.5-4b vocabulary width, 75 column tiles
+])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_quant8_rowwise_matches_reference(impl, shape, dtype):
     """The sharding-preserving rowwise layout (per last-dim channel):
     same shape out, exact parity with core.compression's reference, for
-    lane-padded channel counts and bf16 inputs alike."""
+    channel counts that are not lane multiples, rows that span several
+    column tiles (the two-phase running-absmax grid) and bf16 inputs.
+    Both sides are compiled, as inside the exchange: run op by op, the
+    reference's `amax / 127` is a true division that may land one ulp off
+    the compiled reciprocal multiply."""
     from repro.core import compression as comp
     from repro.kernels.quant8.ops import dequantize_rowwise, quantize_rowwise
     x = jnp.asarray(rng.normal(size=shape) * 2.0, dtype)
-    q, s = quantize_rowwise(x, impl=impl)
-    qr, sr = comp.quantize_rowwise(x)
+    q, s = jax.jit(lambda v: quantize_rowwise(v, impl=impl))(x)
+    qr, sr = jax.jit(comp.quantize_rowwise)(x)
     assert q.shape == x.shape and s.shape == x.shape[:-1] + (1,)
     np.testing.assert_array_equal(np.asarray(q), np.asarray(qr))
-    np.testing.assert_allclose(np.asarray(s), np.asarray(sr), rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(sr))
     got = dequantize_rowwise(q, s, impl=impl)
     want = comp.dequantize_rowwise(qr, sr)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------- flash attention ----------------

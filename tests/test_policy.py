@@ -15,6 +15,7 @@ import subprocess
 import sys
 from pathlib import Path
 
+import jax
 import pytest
 
 from repro.dist import hlo_cost, policy
@@ -250,3 +251,23 @@ def test_cnn_hbm_calibrated_vs_xla(tmp_path):
     finally:
         if art.exists():
             art.unlink()
+
+
+class _Dev:
+    def __init__(self, platform, stats):
+        self.platform, self.device_kind, self._stats = platform, "x", stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+def test_device_hbm_budget_comes_from_the_attached_device():
+    """CPU (AOT dry-runs, tests) keeps the modeled v5e constant; an
+    attached accelerator is sized by its own bytes_limit, and one that
+    reports none is an error rather than a silent 16 GB."""
+    assert policy.device_hbm_bytes(jax.devices("cpu")[0]) == \
+        policy.DEVICE_HBM_BYTES
+    assert policy.device_hbm_bytes(_Dev("tpu", {"bytes_limit": 123})) == 123
+    for stats in ({}, None):
+        with pytest.raises(ValueError, match="bytes_limit"):
+            policy.device_hbm_bytes(_Dev("tpu", stats))

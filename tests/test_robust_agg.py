@@ -199,10 +199,13 @@ def test_robust_agg_with_compression_converges(capsys):
     trains through real exchanges and the loss goes down (the tier-1
     convergence gate for the robust x compressed composition)."""
     from repro.launch import train
-    train.main(["--arch", "granite-20b", "--smoke", "--steps", "12",
-                "--islands", "2", "--local-steps", "2", "--batch", "4",
-                "--seq", "32", "--compress", "q8-topk",
-                "--robust-agg", "trimmed_mean", "--seed", "0"])
+    # run(), not main(): main() also turns on the persistent compilation
+    # cache, which would then stay on for every later test in this worker
+    train.run(train.parse_args([
+        "--arch", "granite-20b", "--smoke", "--steps", "12",
+        "--islands", "2", "--local-steps", "2", "--batch", "4",
+        "--seq", "32", "--compress", "q8-topk",
+        "--robust-agg", "trimmed_mean", "--seed", "0"]))
     lines = capsys.readouterr().out.splitlines()
     losses = [float(ln.split("loss=")[1].split()[0])
               for ln in lines if "loss=" in ln]
