@@ -1,0 +1,19 @@
+"""Published peaks per chip, keyed by JAX's `device_kind`.
+
+Source: Google Cloud documentation, "TPU v5e" (system architecture): per
+chip 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s
+inter-chip interconnect.  A kind that is not listed is an error, never a
+default."""
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes": 16e9, "hbm_bytes_per_s": 819e9,
+                    "ici_bits_per_s": 1600e9},
+}
+
+
+def of(kind: str) -> dict:
+    if kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind '{kind}'")
+    return PEAKS[kind]
