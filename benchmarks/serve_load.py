@@ -84,7 +84,7 @@ def measure(model, params) -> tuple[dict, dict]:
         cell = loadgen.summarize(records, wall)
         cell["qps"] = QPS
         if paged:
-            cell["preemptions"] = loop.preemptions
+            cell["preemptions"] = loop.counters["preemptions"]
             cell["shared_blocks"] = loop.alloc.stats["shared_blocks"]
             cell["evictions"] = loop.alloc.stats["evictions"]
         cells[name] = cell

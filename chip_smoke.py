@@ -204,7 +204,7 @@ def phase_paged(cfg, *, seed: int, n_requests: int = 8,
     later_total = (gen - 1) * len(done)
     res = {"requests": len(done), "tokens": sum(len(r.out) for r in done),
            "prompt_lens": [len(r.prompt) for r in requests],
-           "pool_blocks": nb, "preemptions": loop.preemptions,
+           "pool_blocks": nb, "preemptions": loop.counters["preemptions"],
            "bf16_noise": noise, "first_decisive": first_n,
            "first_agree": first_ok, "later_decisive": later_n,
            "later_agree": later_ok, "agreement_rate": rate,

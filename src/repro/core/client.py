@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.trace import span
+
 
 def softmax_xent(logits, labels):
     logits = logits.astype(jnp.float32)
@@ -115,10 +117,13 @@ class LocalTrainer:
         keys.  Returns params stacked over the cohort axis (C, ...) --
         member i equals `train(params, images[i], labels[i], keys[i])` up
         to vmap's reduction-order jitter (pinned by tests/test_cohort.py).
+        The `flight.fl.train` span covers the dispatch, not the device's
+        work.
         """
-        return self._train_cohort(params, jnp.asarray(images),
-                                  jnp.asarray(labels), keys,
-                                  epochs=int(epochs))
+        with span("fl.train"):
+            return self._train_cohort(params, jnp.asarray(images),
+                                      jnp.asarray(labels), keys,
+                                      epochs=int(epochs))
 
     def train_cohort_checked(self, params, images, labels, keys, epochs: int):
         """`train_cohort` with the per-member non-finite guard: diverged

@@ -27,6 +27,7 @@ import numpy as np
 from jax.sharding import PartitionSpec
 
 from repro.core import aggregation, compression
+from repro.trace import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,12 +46,15 @@ class FLConfig:
 
 def stack_islands(tree, n_islands: int):
     """Tile a single-island pytree into (n_islands, ...) leaves."""
-    return jax.tree.map(
-        lambda x: jnp.broadcast_to(x[None], (n_islands,) + x.shape), tree)
+    with span("fl.stack_islands"):
+        return jax.tree.map(
+            lambda x: jnp.broadcast_to(x[None], (n_islands,) + x.shape),
+            tree)
 
 
 def island_slice(tree, i: int):
-    return jax.tree.map(lambda x: x[i], tree)
+    with span("fl.island_slice"):
+        return jax.tree.map(lambda x: x[i], tree)
 
 
 def cohort_train(trainer, params, shards, keys, epochs: int):
@@ -103,8 +107,16 @@ def fl_aggregate_compressed(stacked_params, base_params, mixing, *,
                             mode: str = "q8", k_frac: float = 0.05,
                             impl: str = "auto"):
     """Beyond-paper: exchange compressed DELTAS from the shared last-sync
-    base instead of raw weights, in ONE jitted step:
-    (sparsify ->) quantize -> mixing collective -> dequantize.
+    base instead of raw weights, leaf by leaf:
+    (sparsify ->) quantize -> dequantize -> mixing contraction.
+
+    It is not jitted.  Called eagerly, as the fog round
+    (hierarchy.hierarchical_sync_aggregate) calls it, every jnp operation
+    of every leaf dispatches its own small program: the subtraction, the
+    casts, the reshapes around the kernel, the `quantize_blocked` kernel
+    program, the dequantising multiply, the tensordot and the add, about
+    eight per leaf per call, issued by the host one by one.  Under an
+    enclosing `jax.jit` the same code traces into one program.
 
     Every island already holds `base_params` (the previous exchange's
     result), so only the compressed delta crosses the pod axis: int8 +

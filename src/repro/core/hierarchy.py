@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import aggregation
+from repro.trace import span
 
 
 # --------------------------------------------------------------------------
@@ -214,20 +215,33 @@ def hierarchical_sync_aggregate(stacked_params, weights: Sequence[float],
     paper's transmission-cost analysis targets.  Equals the flat
     compressed exchange up to one extra quantisation of the fog-stage
     deltas (bounded by the per-row scale; see tests/test_hierarchy.py).
-    `impl` picks the quantiser as in fl_aggregate_compressed."""
+    `impl` picks the quantiser as in fl_aggregate_compressed.
+
+    Host spans (repro/trace.py): `flight.fl.exchange` around the call,
+    and inside it `fl.mixing` (both matrices built in numpy and
+    uploaded), `fl.edge_hop` and `fl.cloud_hop`."""
     from repro.core.federated import fl_aggregate, fl_aggregate_compressed
-    edge_M = jnp.asarray(edge_mixing_matrix(weights, cell_of), jnp.float32)
-    cloud_M = jnp.asarray(cloud_mixing_matrix(weights, cell_of), jnp.float32)
-    if compress in (None, False, "none"):
-        fog = fl_aggregate(stacked_params, edge_M)
-        return fl_aggregate(fog, cloud_M)
-    if base_params is None:
+    plain = compress in (None, False, "none")
+    if not plain and base_params is None:
         raise ValueError("compressed hierarchical exchange needs the "
                          "shared last-sync base_params")
-    fog = fl_aggregate_compressed(stacked_params, base_params, edge_M,
-                                  mode=compress, k_frac=k_frac, impl=impl)
-    return fl_aggregate_compressed(fog, base_params, cloud_M,
-                                   mode=compress, k_frac=k_frac, impl=impl)
+
+    def hop(x, M):
+        if plain:
+            return fl_aggregate(x, M)
+        return fl_aggregate_compressed(x, base_params, M, mode=compress,
+                                       k_frac=k_frac, impl=impl)
+
+    with span("fl.exchange"):
+        with span("fl.mixing"):
+            edge_M = jnp.asarray(edge_mixing_matrix(weights, cell_of),
+                                 jnp.float32)
+            cloud_M = jnp.asarray(cloud_mixing_matrix(weights, cell_of),
+                                  jnp.float32)
+        with span("fl.edge_hop"):
+            fog = hop(stacked_params, edge_M)
+        with span("fl.cloud_hop"):
+            return hop(fog, cloud_M)
 
 
 def hierarchical_async_aggregate(stacked_params, alphas: Sequence[float],

@@ -33,6 +33,12 @@ SUBLANES = 32             # row-tile granularity (native int8 tiling)
 MAX_COLS = 2048           # widest column tile, in elements
 TILE_BYTES = 1 << 20      # fp32 bytes of one input tile
 
+#: The kernels' names: a profiler trace shows each kernel's operation
+#: under its name, in whatever program runs it (both grids of the
+#: quantise share one name).
+QUANT_NAME = "quant8_rowwise"
+DEQUANT_NAME = "quant8_dequant"
+
 #: Inside a compiled program XLA turns core.compression's `amax / 127.0`
 #: into a multiplication by the reciprocal (on CPU and TPU alike); the
 #: kernel multiplies by the same constant so its scales are the compiled
@@ -111,6 +117,7 @@ def quantize_blocked(x, *, interpret: bool = False):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
             interpret=interpret,
+            name=QUANT_NAME,
         )(x)
     return pl.pallas_call(
         functools.partial(_quant_tiled_kernel, cols=cols),
@@ -125,6 +132,7 @@ def quantize_blocked(x, *, interpret: bool = False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name=QUANT_NAME,
     )(x)
 
 
@@ -144,4 +152,5 @@ def dequantize_blocked(q, s, *, out_dtype=jnp.float32,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name=DEQUANT_NAME,
     )(q, s.astype(jnp.float32))

@@ -184,8 +184,8 @@ def main(argv=None):
         print(f"[serve] paged loop: {len(done)} reqs, {toks} tokens in "
               f"{wall*1e3:.1f}ms ({toks/max(wall,1e-9):.0f} tok/s); "
               f"pool {nb}x{args.block_size}, "
-              f"shared {loop.alloc.stats['shared_blocks']} blocks, "
-              f"{loop.preemptions} preemptions")
+              f"shared {loop.alloc.stats['shared_blocks']} blocks; "
+              f"counters {loop.counters}")
         print(f"[serve] sample generations (first 12 ids): "
               f"{[r.out[:12] for r in done[:4]]}")
         return

@@ -17,7 +17,9 @@ Two cache disciplines behind one Request/submit/tick API:
   so any prompt length streams through a bounded number of jit cache
   entries, lazy block growth during decode, and preemption (requeue the
   youngest sequence) when the pool runs dry.  Greedy decode is
-  token-identical to ServeLoop (tests/test_serve_loop.py).
+  token-identical to ServeLoop (tests/test_serve_loop.py).  Its tick
+  records host spans (`flight.serve.*`, repro/trace.py) around each
+  phase, and `counters` counts the work where it happens.
 
 CPU-runnable at smoke scale; the same loops drive TPU serving, with the
 weight layout (stationary / hybrid / fsdp) picked per model by the
@@ -34,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.paging import BlockAllocator, OutOfBlocks
+from repro.trace import span
 
 
 @dataclasses.dataclass
@@ -227,7 +230,11 @@ class PagedServeLoop(_ServeBase):
         self._seq_of_slot: dict[int, int] = {}
         self._admit_order: list[int] = []   # slots, oldest first
         self._seq_counter = 0
-        self.preemptions = 0
+        # work done, counted where it happens; host_syncs counts every
+        # blocking device->host read (each int() of a device value)
+        self.counters = dict.fromkeys(
+            ("decode_steps", "prefill_chunks", "host_syncs", "admissions",
+             "preemptions"), 0)
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
         self._chunk_prefill = jax.jit(self._chunk_impl, donate_argnums=(1,))
 
@@ -262,61 +269,75 @@ class PagedServeLoop(_ServeBase):
     def _admit(self):
         while self.queue and self.free:
             req = self.queue[0]
-            prompt = np.asarray(req.prompt, np.int32)
-            T = len(prompt)
-            if (T + 1 + self.bs - 1) // self.bs > self.alloc.num_blocks:
+            with span("serve.admit", rid=req.rid):
+                if not self._admit_head(req):
+                    return                 # head-of-line waits for blocks
+
+    def _admit_head(self, req: Request) -> bool:
+        """Admit and prefill the queue's head; False when the pool has no
+        room for it yet."""
+        prompt = np.asarray(req.prompt, np.int32)
+        T = len(prompt)
+        if (T + 1 + self.bs - 1) // self.bs > self.alloc.num_blocks:
+            raise RuntimeError(
+                f"prompt of {T} tokens can never fit the "
+                f"{self.alloc.num_blocks}x{self.bs} block pool")
+        sid = self._seq_counter
+        try:
+            res = self.alloc.admit(sid, prompt.tolist(), reserve=1)
+        except OutOfBlocks:
+            if not self.live and not self._preempt_youngest(protect=-1):
                 raise RuntimeError(
-                    f"prompt of {T} tokens can never fit the "
-                    f"{self.alloc.num_blocks}x{self.bs} block pool")
-            sid = self._seq_counter
-            try:
-                res = self.alloc.admit(sid, prompt.tolist(), reserve=1)
-            except OutOfBlocks:
-                if not self.live and not self._preempt_youngest(protect=-1):
-                    raise RuntimeError(
-                        "admission stalled with no live sequences: "
-                        "block pool exhausted by the prefix cache?")
-                return                     # head-of-line waits for blocks
-            self._seq_counter += 1
-            self.queue.pop(0)
-            slot = self.free.pop(0)
-            self._seq_of_slot[slot] = sid
-            self._admit_order.append(slot)
-            self._set_table(slot, res.table)
-            nxt = self._prefill_chunks(slot, prompt,
-                                       res.n_shared_tokens, T)
-            self._next = self._next.at[slot].set(int(nxt))
-            self.lengths[slot] = T
-            req.out.append(int(nxt))
-            self.live[slot] = req
+                    "admission stalled with no live sequences: "
+                    "block pool exhausted by the prefix cache?")
+            return False
+        self._seq_counter += 1
+        self.queue.pop(0)
+        slot = self.free.pop(0)
+        self._seq_of_slot[slot] = sid
+        self._admit_order.append(slot)
+        self._set_table(slot, res.table)
+        self.counters["admissions"] += 1
+        nxt = self._prefill_chunks(slot, prompt, res.n_shared_tokens, T,
+                                   rid=req.rid)
+        self._next = self._next.at[slot].set(nxt)
+        self.lengths[slot] = T
+        req.out.append(nxt)
+        self.live[slot] = req
+        return True
 
     def _set_table(self, slot: int, table: list[int]):
         self.bt[slot] = 0
         self.bt[slot, : len(table)] = table
 
     def _prefill_chunks(self, slot: int, prompt: np.ndarray, start: int,
-                        T: int) -> int:
+                        T: int, rid: int) -> int:
         """Stream prompt positions [start, T) through the pool in
         block-aligned chunks; the tail pads to a power-of-two bucket
         (positions -1 => writes dropped, logits taken at the last valid
         row).  `start` skips positions covered by shared prefix blocks --
-        their K/V is already resident."""
-        bt_row = jnp.array(self.bt[slot: slot + 1], copy=True)  # see tick
-        pos = start
-        nxt = None
-        while pos < T:
-            c = min(self.chunk, T - pos)
-            cb = c if c == self.chunk else _bucket(c)
-            toks = np.zeros((1, cb), np.int32)
-            toks[0, :c] = prompt[pos: pos + c]
-            pv = np.full((1, cb), -1, np.int32)
-            pv[0, :c] = np.arange(pos, pos + c, dtype=np.int32)
-            nxt, self.pages = self._chunk_prefill(
-                self.params, self.pages, jnp.asarray(toks),
-                jnp.asarray(pv), bt_row,
-                jnp.asarray([c - 1], jnp.int32))
-            pos += c
-        return int(nxt[0])
+        their K/V is already resident.  Returns the first output token,
+        read back to the host."""
+        chunks = -(-(T - start) // self.chunk)
+        with span("serve.prefill", rid=rid, chunks=chunks):
+            bt_row = jnp.array(self.bt[slot: slot + 1], copy=True)  # see tick
+            pos = start
+            nxt = None
+            while pos < T:
+                c = min(self.chunk, T - pos)
+                cb = c if c == self.chunk else _bucket(c)
+                toks = np.zeros((1, cb), np.int32)
+                toks[0, :c] = prompt[pos: pos + c]
+                pv = np.full((1, cb), -1, np.int32)
+                pv[0, :c] = np.arange(pos, pos + c, dtype=np.int32)
+                nxt, self.pages = self._chunk_prefill(
+                    self.params, self.pages, jnp.asarray(toks),
+                    jnp.asarray(pv), bt_row,
+                    jnp.asarray([c - 1], jnp.int32))
+                self.counters["prefill_chunks"] += 1
+                pos += c
+            self.counters["host_syncs"] += 1
+            return int(nxt[0])
 
     # -- eviction / preemption -------------------------------------------
     def _release(self, slot: int):
@@ -338,7 +359,7 @@ class PagedServeLoop(_ServeBase):
             req.out = []
             self.queue.insert(0, req)
             self._release(slot)
-            self.preemptions += 1
+            self.counters["preemptions"] += 1
             return True
         return False
 
@@ -362,26 +383,32 @@ class PagedServeLoop(_ServeBase):
 
     # -- main tick --------------------------------------------------------
     def tick(self) -> list[Request]:
-        self._admit()
-        if not self.live:
-            return []
-        self._grow_tables()
-        # free slots decode with position -1: their K/V write is dropped
-        # (paged_kv_write) and their output ignored
-        positions = np.full(self.B, -1, np.int32)
-        for slot in self.live:
-            positions[slot] = self.lengths[slot]
-        nxt, self.pages = self._decode(
-            self.params, self.pages, jnp.array(self.bt, copy=True),
-            self._next[:, None], jnp.asarray(positions[:, None]))
-        self._next = nxt.astype(jnp.int32)
-        finished = []
-        for slot, req in list(self.live.items()):
-            self.lengths[slot] += 1
-            req.out.append(int(nxt[slot]))
-            if len(req.out) >= req.max_new:
-                req.done = True
-                finished.append(req)
-                del self.live[slot]
-                self._release(slot)
-        return finished
+        with span("serve.tick"):
+            self._admit()
+            if not self.live:
+                return []
+            with span("serve.grow"):
+                self._grow_tables()
+            with span("serve.step"):
+                # free slots decode with position -1: their K/V write is
+                # dropped (paged_kv_write) and their output ignored
+                positions = np.full(self.B, -1, np.int32)
+                for slot in self.live:
+                    positions[slot] = self.lengths[slot]
+                nxt, self.pages = self._decode(
+                    self.params, self.pages, jnp.array(self.bt, copy=True),
+                    self._next[:, None], jnp.asarray(positions[:, None]))
+                self._next = nxt.astype(jnp.int32)
+                self.counters["decode_steps"] += 1
+            with span("serve.readback"):
+                finished = []
+                for slot, req in list(self.live.items()):
+                    self.lengths[slot] += 1
+                    req.out.append(int(nxt[slot]))
+                    self.counters["host_syncs"] += 1
+                    if len(req.out) >= req.max_new:
+                        req.done = True
+                        finished.append(req)
+                        del self.live[slot]
+                        self._release(slot)
+            return finished

@@ -499,9 +499,10 @@ def attention_apply(p, cfg, x, positions, *, mode="train", cache=None,
         # (which already holds any shared-prefix blocks -- their
         # positions are simply never re-computed).
         assert not window, "paged cache does not support sliding windows"
-        kp, vp = paged_kv_write(cache["kp"], cache["vp"], cache["bt"],
-                                kk, vv, positions)
-        k_seq, v_seq = paged_gather_kv(kp, vp, cache["bt"])
+        with jax.named_scope("paged_gather"):
+            kp, vp = paged_kv_write(cache["kp"], cache["vp"], cache["bt"],
+                                    kk, vv, positions)
+            k_seq, v_seq = paged_gather_kv(kp, vp, cache["bt"])
         out = paged_chunk_attention(q, k_seq, v_seq, positions)
         new_cache = {"kp": kp, "vp": vp}
     elif mode == "chunk_prefill":
@@ -525,8 +526,9 @@ def attention_apply(p, cfg, x, positions, *, mode="train", cache=None,
         assert not window, "paged cache does not support sliding windows"
         kp, vp, bt = cache["kp"], cache["vp"], cache["bt"]
         cache_len = cache["len"]
-        kp, vp = paged_kv_write(kp, vp, bt, kk, vv, cache_len[:, None])
-        k_seq, v_seq = paged_gather_kv(kp, vp, bt)
+        with jax.named_scope("paged_gather"):
+            kp, vp = paged_kv_write(kp, vp, bt, kk, vv, cache_len[:, None])
+            k_seq, v_seq = paged_gather_kv(kp, vp, bt)
         out = decode_attention(q, k_seq, v_seq, cache_len + 1)
         new_cache = {"kp": kp, "vp": vp, "bt": bt, "len": cache_len + 1}
     elif mode == "decode":

@@ -4,6 +4,11 @@ Modes:
   train   -- full-sequence forward, returns (logits, aux)
   prefill -- full-sequence forward, returns (logits, cache)
   decode  -- single-token step with KV cache, returns (logits, cache)
+
+Named scopes (`jax.named_scope`) mark each part's operations in the
+compiled program's metadata, where a profiler trace finds them: embed,
+attention (with paged_gather inside it on the paged paths), mlp / moe and
+head.  They are metadata only and leave the compiled code as it was.
 """
 from __future__ import annotations
 
@@ -54,16 +59,18 @@ def paged_cache_defs(cfg, batch: int, num_blocks: int, block_size: int,
 
 
 def _block_apply(p, cfg, x, positions, mode, cache):
-    h = L.apply_norm(p["ln1"], x, cfg.norm)
-    a, new_cache = L.attention_apply(p["attn"], cfg, h, positions,
-                                     mode=mode, cache=cache)
-    x = x + a
-    h = L.apply_norm(p["ln2"], x, cfg.norm)
-    if "moe" in p:
-        m, aux = L.moe_apply(p["moe"], cfg, h)
-    else:
-        m, aux = L.mlp_apply(p["mlp"], cfg, h), 0.0
-    return x + m, new_cache, aux
+    with jax.named_scope("attention"):
+        h = L.apply_norm(p["ln1"], x, cfg.norm)
+        a, new_cache = L.attention_apply(p["attn"], cfg, h, positions,
+                                         mode=mode, cache=cache)
+        x = x + a
+    with jax.named_scope("moe" if "moe" in p else "mlp"):
+        h = L.apply_norm(p["ln2"], x, cfg.norm)
+        if "moe" in p:
+            m, aux = L.moe_apply(p["moe"], cfg, h)
+        else:
+            m, aux = L.mlp_apply(p["mlp"], cfg, h), 0.0
+        return x + m, new_cache, aux
 
 
 def _embed_inputs(params, cfg, batch_inputs):
@@ -78,7 +85,8 @@ def _embed_inputs(params, cfg, batch_inputs):
 
 
 def lm_apply(params, cfg, batch_inputs, *, mode="train", cache=None):
-    x = _embed_inputs(params, cfg, batch_inputs)
+    with jax.named_scope("embed"):
+        x = _embed_inputs(params, cfg, batch_inputs)
     B, T = x.shape[0], x.shape[1]
     if mode == "decode":
         # cache["len"] is stacked (L, B); all layers share the same length.
@@ -117,16 +125,17 @@ def lm_apply(params, cfg, batch_inputs, *, mode="train", cache=None):
     else:
         (x, aux), new_cache = lax.scan(body, (x, 0.0), params["layers"])
 
-    if mode == "prefill":
-        x = x[:, -1:]  # serving needs only the last position's logits
-    elif mode == "chunk_prefill":
-        # only the last VALID position's logits matter (tail chunks are
-        # padded to a bucket length)
-        li = batch_inputs["last_index"].reshape(B, 1, 1)
-        x = jnp.take_along_axis(x, li, axis=1)
-    x = L.apply_norm(params["final_norm"], x, cfg.norm)
-    logits = L.unembed_apply(params["embed"], x)
-    logits = constrain(logits, ("batch", None, "vocab"))
+    with jax.named_scope("head"):
+        if mode == "prefill":
+            x = x[:, -1:]  # serving needs only the last position's logits
+        elif mode == "chunk_prefill":
+            # only the last VALID position's logits matter (tail chunks
+            # are padded to a bucket length)
+            li = batch_inputs["last_index"].reshape(B, 1, 1)
+            x = jnp.take_along_axis(x, li, axis=1)
+        x = L.apply_norm(params["final_norm"], x, cfg.norm)
+        logits = L.unembed_apply(params["embed"], x)
+        logits = constrain(logits, ("batch", None, "vocab"))
     if mode == "train":
         return logits, aux
     return logits, new_cache

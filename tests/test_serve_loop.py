@@ -135,7 +135,7 @@ def test_paged_preemption_requeues_exactly():
                            block_size=8, chunk=16)
     got = _drain(ploop, prompts, max_new=16)
     assert got == want
-    assert ploop.preemptions >= 1
+    assert ploop.counters["preemptions"] >= 1
     ploop.alloc.check_invariants()
 
 

@@ -84,3 +84,23 @@ def test_q8_exchange_compiles_with_islands_over_pod(topo, monkeypatch):
     with jax.set_mesh(mesh):
         text = step.lower(leaf, leaf, mixing).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("shape", [(256, 1024), (256, 4096)])
+def test_quant8_kernels_carry_their_names(one_chip, shape):
+    """The compiled custom call is named after the kernel (one column
+    tile, then column tiles), so a profiler trace finds the kernel's
+    operation by that name in whatever program runs it."""
+    import re
+    from repro.kernels.quant8.kernel import (DEQUANT_NAME, QUANT_NAME,
+                                             dequantize_blocked,
+                                             quantize_blocked)
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    q = jax.ShapeDtypeStruct(shape, jnp.int8, sharding=one_chip)
+    s = jax.ShapeDtypeStruct((shape[0], 1), jnp.float32, sharding=one_chip)
+    calls = lambda text: re.findall(r"%(\w+?)(?:\.\d+)? = .*custom-call\(",
+                                    text)
+    quant = jax.jit(functools.partial(quantize_blocked, interpret=False))
+    dequant = jax.jit(functools.partial(dequantize_blocked, interpret=False))
+    assert calls(quant.lower(x).compile().as_text()) == [QUANT_NAME]
+    assert calls(dequant.lower(q, s).compile().as_text()) == [DEQUANT_NAME]
