@@ -1,0 +1,41 @@
+"""Public paged decode attention: the Pallas kernel where the shapes
+allow it on a TPU, the gather + dense reference elsewhere."""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from repro.kernels.paged_attention import kernel
+from repro.kernels.paged_attention.ref import paged_decode_attention_ref
+
+SUBLANES = 16     # bf16 sublane tile: a block's BS rows fill whole tiles
+LANES = 128
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def kernel_fits(q, kp) -> bool:
+    """The kernel's tiling needs lane-wide heads, whole bf16 tiles per
+    block, and a bf16 pool."""
+    D, BS = q.shape[-1], kp.shape[1]
+    return (D % LANES == 0 and BS % SUBLANES == 0
+            and kp.dtype == jnp.bfloat16)
+
+
+def paged_decode_attention(q, kp, vp, bt, lengths, *, impl: str = "auto"):
+    """q: (B, 1, H, D) over the (NB, BS, Hkv, D) pools kp/vp through the
+    (B, nbmax) block tables bt; lengths (B,) counts the positions each
+    slot attends (0 for a free slot, whose output is not read).
+
+    impl="auto" runs the kernel on a TPU when `kernel_fits`, the
+    reference otherwise; "pallas" (interpret mode off the TPU) and "ref"
+    force one path, for tests.  The kernel is not partitioned: under a
+    multi-device mesh it would need a shard_map."""
+    if impl == "auto":
+        impl = "pallas" if _on_tpu() and kernel_fits(q, kp) else "ref"
+    if impl == "ref":
+        return paged_decode_attention_ref(q, kp, vp, bt, lengths)
+    return kernel.paged_decode_attention(q, kp, vp, bt, lengths,
+                                         interpret=not _on_tpu())

@@ -231,10 +231,11 @@ class PagedServeLoop(_ServeBase):
         self._admit_order: list[int] = []   # slots, oldest first
         self._seq_counter = 0
         # work done, counted where it happens; host_syncs counts every
-        # blocking device->host read (each int() of a device value)
+        # blocking device->host read (each int() of a device value), and
+        # kv_blocks_read the pool blocks the decode steps' live slots hold
         self.counters = dict.fromkeys(
             ("decode_steps", "prefill_chunks", "host_syncs", "admissions",
-             "preemptions"), 0)
+             "preemptions", "kv_blocks_read"), 0)
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
         self._chunk_prefill = jax.jit(self._chunk_impl, donate_argnums=(1,))
 
@@ -395,6 +396,8 @@ class PagedServeLoop(_ServeBase):
                 positions = np.full(self.B, -1, np.int32)
                 for slot in self.live:
                     positions[slot] = self.lengths[slot]
+                    self.counters["kv_blocks_read"] += \
+                        -(-(int(self.lengths[slot]) + 1) // self.bs)
                 nxt, self.pages = self._decode(
                     self.params, self.pages, jnp.array(self.bt, copy=True),
                     self._next[:, None], jnp.asarray(positions[:, None]))

@@ -528,8 +528,9 @@ def attention_apply(p, cfg, x, positions, *, mode="train", cache=None,
         cache_len = cache["len"]
         with jax.named_scope("paged_gather"):
             kp, vp = paged_kv_write(kp, vp, bt, kk, vv, cache_len[:, None])
-            k_seq, v_seq = paged_gather_kv(kp, vp, bt)
-        out = decode_attention(q, k_seq, v_seq, cache_len + 1)
+        # the write comes first: the new token attends to itself
+        from repro.kernels.paged_attention import ops as paged_ops
+        out = paged_ops.paged_decode_attention(q, kp, vp, bt, cache_len + 1)
         new_cache = {"kp": kp, "vp": vp, "bt": bt, "len": cache_len + 1}
     elif mode == "decode":
         spec = kvcache.spec_of(cfg)

@@ -11,6 +11,7 @@ every test worker collects the same tests and only the worker given this
 file loads the TPU library.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -91,7 +92,6 @@ def test_quant8_kernels_carry_their_names(one_chip, shape):
     """The compiled custom call is named after the kernel (one column
     tile, then column tiles), so a profiler trace finds the kernel's
     operation by that name in whatever program runs it."""
-    import re
     from repro.kernels.quant8.kernel import (DEQUANT_NAME, QUANT_NAME,
                                              dequantize_blocked,
                                              quantize_blocked)
@@ -104,3 +104,57 @@ def test_quant8_kernels_carry_their_names(one_chip, shape):
     dequant = jax.jit(functools.partial(dequantize_blocked, interpret=False))
     assert calls(quant.lower(x).compile().as_text()) == [QUANT_NAME]
     assert calls(dequant.lower(q, s).compile().as_text()) == [DEQUANT_NAME]
+
+
+@pytest.mark.parametrize("B,H,Hkv,BS", [
+    (8, 20, 20, 16),      # qwen1.5-4b: 20 KV heads, stored in 24 rows
+    (8, 32, 2, 16),       # chatglm3-6b: 16 query heads per KV head
+    (4, 16, 4, 32),
+])
+def test_paged_attention_kernel_compiles_for_v5e(one_chip, B, H, Hkv, BS):
+    from repro.kernels.paged_attention.kernel import (NAME,
+                                                      paged_decode_attention)
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                  sharding=one_chip)
+    pool = S((512, BS, Hkv, 128), jnp.bfloat16)
+    step = jax.jit(functools.partial(paged_decode_attention,
+                                     interpret=False))
+    text = step.lower(S((B, 1, H, 128), jnp.bfloat16), pool, pool,
+                      S((B, 512), jnp.int32), S((B,), jnp.int32)
+                      ).compile().as_text()
+    assert NAME in text and "tpu_custom_call" in text
+
+
+def test_paged_decode_step_reads_the_pool_through_the_kernel(one_chip,
+                                                            monkeypatch):
+    """PagedServeLoop's decode step at qwen1.5-4b's widths (40 layers, a
+    512 x 16 pool, batch 8), compiled for a v5e as a TPU backend would
+    run it: the paged attention is the named kernel, and the gathered
+    (B * nbmax * BS, Hkv, D) f32 copy of K is gone from the program."""
+    from repro.configs import get_config
+    from repro.kernels.paged_attention import ops
+    from repro.launch.serve_loop import PagedServeLoop
+    from repro.models import build_model
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = get_config("qwen1.5-4b")
+    model = build_model(cfg)
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(model.init,
+                                                  jax.random.key(0)))
+    B, NB, BS = 8, 512, 16
+    pool = jax.ShapeDtypeStruct(
+        (cfg.num_layers, NB, BS, cfg.num_kv_heads, cfg.head_dim),
+        jnp.bfloat16, sharding=one_chip)
+    i32 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                             sharding=one_chip)
+    # the step needs only the model; no pool is allocated here
+    loop = PagedServeLoop.__new__(PagedServeLoop)
+    loop.model, loop.rules = model, None
+    text = jax.jit(loop._decode_impl, donate_argnums=(1,)).lower(
+        params, {"kp": pool, "vp": pool}, i32((B, NB)), i32((B, 1)),
+        i32((B, 1))).compile().as_text()
+    assert re.search(r"%paged_decode_attention(\.\d+)? = .*custom-call\(",
+                     text)
+    gathered = f"f32[{B * NB * BS},{cfg.num_kv_heads},{cfg.head_dim}]"
+    assert gathered not in text

@@ -106,23 +106,27 @@ def test_paged_tick_spans_nest(tmp_path, granite):
 
 def drain_watching(loop, reqs):
     """Drain tick by tick, observing from outside what the counters
-    count: live slots decoded per tick, and preemptions (a preempted
-    request's output list is replaced)."""
+    count: live slots decoded per tick, the pool blocks those slots hold
+    (a request that has emitted n tokens wrote position T + n - 2 in the
+    step, so holds ceil((T + n - 1) / block_size) blocks), and
+    preemptions (a preempted request's output list is replaced)."""
     for r in reqs:
         loop.submit(r)
     seen = {r.rid: r.out for r in reqs}
-    live_per_step, preempted, done = [], 0, []
+    live_per_step, blocks, preempted, done = [], 0, 0, []
     while loop.live or loop.queue:
         finished = loop.tick()
         done += finished
-        decoded = len(loop.live) + len(finished)
+        decoded = list(loop.live.values()) + finished
         if decoded:
-            live_per_step.append(decoded)
+            live_per_step.append(len(decoded))
+        blocks += sum(math.ceil((len(r.prompt) + len(r.out) - 1) / loop.bs)
+                      for r in decoded)
         for r in reqs:
             if r.out is not seen[r.rid]:
                 preempted += 1
                 seen[r.rid] = r.out
-    return done, live_per_step, preempted
+    return done, live_per_step, blocks, preempted
 
 
 def test_paged_counters_exact(granite):
@@ -132,10 +136,11 @@ def test_paged_counters_exact(granite):
     loop = PagedServeLoop(model, params, max_batch=3, num_blocks=9,
                           block_size=8, chunk=16)
     reqs = requests(cfg, (21, 23, 22), max_new=16)
-    done, live_per_step, preempted = drain_watching(loop, reqs)
+    done, live_per_step, blocks, preempted = drain_watching(loop, reqs)
     c = loop.counters
     assert len(done) == 3 and preempted >= 1
     assert c["preemptions"] == preempted
+    assert c["kv_blocks_read"] == blocks
     assert c["admissions"] == len(reqs) + preempted
     assert c["decode_steps"] == len(live_per_step)
     assert c["host_syncs"] == sum(live_per_step) + c["admissions"]
@@ -149,14 +154,17 @@ def test_paged_counters_without_preemption(granite):
     loop = PagedServeLoop(model, params, max_batch=2, num_blocks=64,
                           block_size=8, chunk=16)
     reqs = requests(cfg, (5, 17, 33), max_new=3, seed=5)
-    done, live_per_step, preempted = drain_watching(loop, reqs)
+    done, live_per_step, blocks, preempted = drain_watching(loop, reqs)
     assert len(done) == 3 and preempted == 0
+    # two decode steps per request, at positions T and T + 1 (blocks of 8)
+    assert blocks == (1 + 1) + (3 + 3) + (5 + 5)
     assert loop.counters == {
         "decode_steps": len(live_per_step),
         "prefill_chunks": 1 + 2 + 3,
         "host_syncs": sum(live_per_step) + 3,
         "admissions": 3,
-        "preemptions": 0}
+        "preemptions": 0,
+        "kv_blocks_read": blocks}
 
 
 def test_fog_round_spans(tmp_path):
