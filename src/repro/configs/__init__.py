@@ -19,6 +19,7 @@ _ARCHS = {
     "recurrentgemma-9b": "recurrentgemma_9b",
     "falcon-mamba-7b": "falcon_mamba_7b",
     "seamless-m4t-large-v2": "seamless_m4t_large_v2",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     # the paper's own workloads (Tier-A FL experiments)
     "flight-cnn-mnist": "flight_cnn",
     "flight-cnn-cifar": "flight_cnn",

@@ -1,1 +1,2 @@
-from repro.kernels.paged_attention.ops import paged_decode_attention
+from repro.kernels.paged_attention.ops import (paged_decode_attention,
+                                              paged_latent_attention)

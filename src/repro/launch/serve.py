@@ -98,14 +98,17 @@ def load(cfg, *, batch: int, seq_len: int, layout: str = "auto",
 
 
 def serve_paged(model, params, requests, *, max_batch: int,
-                block_size: int, num_blocks: int, layout: str):
+                block_size: int, num_blocks: int, layout: str,
+                max_seq_len: int | None = None):
     """Serve `requests` through PagedServeLoop; at most `max_batch` run at
-    once, so later ones join mid-flight.  Returns (finished requests,
-    loop, wall seconds)."""
+    once, so later ones join mid-flight.  `max_seq_len` bounds a request's
+    prompt + output tokens and the width of the block tables.  Returns
+    (finished requests, loop, wall seconds)."""
     from repro.launch.serve_loop import PagedServeLoop
     loop = PagedServeLoop(model, params, max_batch=max_batch,
                           num_blocks=num_blocks, block_size=block_size,
-                          chunk=max(block_size * 4, 32), layout=layout)
+                          chunk=max(block_size * 4, 32), layout=layout,
+                          max_seq_len=max_seq_len)
     for r in requests:
         loop.submit(r)
     t0 = time.time()
@@ -161,6 +164,10 @@ def main(argv=None):
     ap.add_argument("--num-blocks", type=int, default=0,
                     help="KV block pool size (default: sized so the pool "
                          "covers batch x (prompt+gen))")
+    ap.add_argument("--max-seq-len", type=int, default=None,
+                    help="paged: the most prompt + output tokens of a "
+                         "request, which bounds the block tables' width "
+                         "(default: a table may span the whole pool)")
     args = ap.parse_args(argv)
     enable_compile_cache()
 
@@ -179,7 +186,8 @@ def main(argv=None):
                     for i in range(2 * B)]   # oversubscribe: mid-flight joins
         done, loop, wall = serve_paged(model, params, requests, max_batch=B,
                                        block_size=args.block_size,
-                                       num_blocks=nb, layout=decision.layout)
+                                       num_blocks=nb, layout=decision.layout,
+                                       max_seq_len=args.max_seq_len)
         toks = sum(len(r.out) for r in done)
         print(f"[serve] paged loop: {len(done)} reqs, {toks} tokens in "
               f"{wall*1e3:.1f}ms ({toks/max(wall,1e-9):.0f} tok/s); "

@@ -180,10 +180,11 @@ class ServeLoop(_ServeBase):
         nxt, self.cache = self._decode(
             self.params, self.cache, self._next[:, None], positions)
         self._next = nxt.astype(jnp.int32)
+        out = np.asarray(nxt)          # one device->host read per tick
         finished = []
         for slot, req in list(self.live.items()):
             self.lengths[slot] += 1
-            req.out.append(int(nxt[slot]))
+            req.out.append(int(out[slot]))
             if len(req.out) >= req.max_new:
                 req.done = True
                 finished.append(req)
@@ -208,30 +209,36 @@ class PagedServeLoop(_ServeBase):
 
     def __init__(self, model, params, *, max_batch: int = 4,
                  num_blocks: int = 64, block_size: int = 16,
-                 chunk: int = 64, layout: str = "auto"):
-        assert model.supports_paged_cache, (
-            f"{model.cfg.name}: paged serving needs a growing KV cache "
-            f"(family={model.cfg.family}); use ServeLoop")
+                 chunk: int = 64, layout: str = "auto",
+                 max_seq_len: int | None = None):
+        """max_seq_len bounds prompt + output tokens of a request, and so
+        the width of the block tables (nbmax); without it a table may
+        span the whole pool."""
         assert chunk % block_size == 0, "chunk must be block-aligned"
+        # a table can never exceed the pool
+        nbmax = num_blocks if max_seq_len is None else min(
+            num_blocks, -(-max_seq_len // block_size))
+        # raises ValueError for a model whose cache cannot be paged
+        defs = model.paged_cache_defs(max_batch, num_blocks, block_size,
+                                      nbmax)
         super().__init__(model, params, max_batch=max_batch, layout=layout)
         self.alloc = BlockAllocator(num_blocks, block_size)
         self.bs = block_size
-        self.nbmax = num_blocks            # a table can never exceed the pool
+        self.nbmax = nbmax
+        self.max_seq_len = max_seq_len
         self.chunk = chunk
         from repro.models.param import is_def
-        defs = model.paged_cache_defs(max_batch, num_blocks, block_size,
-                                      self.nbmax)
         full = jax.tree.map(lambda d: jnp.zeros(d.shape, d.dtype), defs,
                             is_leaf=is_def)
         # only the block pool lives on device between ticks; tables and
         # lengths are rebuilt from host truth every step
-        self.pages = {"kp": full["kp"], "vp": full["vp"]}
+        self.pages = {k: v for k, v in full.items() if k not in ("bt", "len")}
         self.bt = np.zeros((max_batch, self.nbmax), np.int32)
         self._seq_of_slot: dict[int, int] = {}
         self._admit_order: list[int] = []   # slots, oldest first
         self._seq_counter = 0
         # work done, counted where it happens; host_syncs counts every
-        # blocking device->host read (each int() of a device value), and
+        # blocking device->host read (one a prefill, one a decode step), and
         # kv_blocks_read the pool blocks the decode steps' live slots hold
         self.counters = dict.fromkeys(
             ("decode_steps", "prefill_chunks", "host_syncs", "admissions",
@@ -247,14 +254,14 @@ class PagedServeLoop(_ServeBase):
         return jnp.broadcast_to(x[None], (L,) + x.shape)
 
     def _decode_impl(self, params, pages, bt, tokens, positions):
-        cache = {"kp": pages["kp"], "vp": pages["vp"],
-                 "bt": self._stack(bt), "len": self._stack(positions[:, 0])}
+        cache = {**pages, "bt": self._stack(bt),
+                 "len": self._stack(positions[:, 0])}
         with self._rules_ctx():
             logits, cache = self.model.apply(
                 params, {"tokens": tokens, "positions": positions},
                 mode="decode", cache=cache)
         nxt = jnp.argmax(logits[:, -1].astype(jnp.float32), axis=-1)
-        return nxt, {"kp": cache["kp"], "vp": cache["vp"]}
+        return nxt, {k: cache[k] for k in pages}
 
     def _chunk_impl(self, params, pages, tokens, positions, bt_row,
                     last_index):
@@ -279,6 +286,10 @@ class PagedServeLoop(_ServeBase):
         room for it yet."""
         prompt = np.asarray(req.prompt, np.int32)
         T = len(prompt)
+        if self.max_seq_len is not None and T + req.max_new > self.max_seq_len:
+            raise ValueError(
+                f"request {req.rid}: {T} prompt + {req.max_new} new tokens "
+                f"exceed max_seq_len {self.max_seq_len}")
         if (T + 1 + self.bs - 1) // self.bs > self.alloc.num_blocks:
             raise RuntimeError(
                 f"prompt of {T} tokens can never fit the "
@@ -404,11 +415,13 @@ class PagedServeLoop(_ServeBase):
                 self._next = nxt.astype(jnp.int32)
                 self.counters["decode_steps"] += 1
             with span("serve.readback"):
+                # one device->host read for every live slot's token
+                out = np.asarray(nxt)
+                self.counters["host_syncs"] += 1
                 finished = []
                 for slot, req in list(self.live.items()):
                     self.lengths[slot] += 1
-                    req.out.append(int(nxt[slot]))
-                    self.counters["host_syncs"] += 1
+                    req.out.append(int(out[slot]))
                     if len(req.out) >= req.max_new:
                         req.done = True
                         finished.append(req)

@@ -176,6 +176,47 @@ def paged_attention_cache_defs(cfg, batch, num_blocks, block_size,
     }
 
 
+#: lanes of a TPU vector register: a latent row is stored padded to a
+#: multiple of this, so that a pool block is whole lane tiles
+LANES = 128
+
+
+def latent_width(cfg) -> int:
+    """Values in a latent row: c_kv (kv_lora_rank) || k_pe (rope dims)."""
+    return cfg.kv_lora_rank + cfg.qk_rope_head_dim
+
+
+def latent_lanes(cfg) -> int:
+    """The row as stored: latent_width padded with zeros to whole lane
+    tiles (Moonlight: 512 + 64 = 576 values in 640 lanes)."""
+    return -(-latent_width(cfg) // LANES) * LANES
+
+
+def latent_cache_defs(cfg, batch: int, seq_len: int):
+    """MLA's contiguous cache, per layer: one latent row per position.
+    The layout policy sizes it; MLA decodes only through the paged pool."""
+    return {
+        "latent": pdef((batch, seq_len, latent_width(cfg)),
+                       ("batch", "kv_seq", None), dtype=jnp.bfloat16,
+                       init="zeros"),
+        "len": pdef((batch,), ("batch",), dtype=jnp.int32, init="zeros"),
+    }
+
+
+def paged_latent_cache_defs(cfg, batch, num_blocks, block_size,
+                            max_blocks_per_seq):
+    """MLA's paged cache, per layer: a pool of latent rows (NB, BS,
+    latent_lanes) shared by all slots -- one row per token, read by every
+    head -- plus per-slot block tables and lengths."""
+    return {
+        "latent": pdef((num_blocks, block_size, latent_lanes(cfg)),
+                       (None, None, None), dtype=jnp.bfloat16, init="zeros"),
+        "bt": pdef((batch, max_blocks_per_seq), ("batch", None),
+                   dtype=jnp.int32, init="zeros"),
+        "len": pdef((batch,), ("batch",), dtype=jnp.int32, init="zeros"),
+    }
+
+
 # ---------------------------------------------------------------------------
 # Mesh resolution (policy-side): which specs are real on a given mesh
 # ---------------------------------------------------------------------------

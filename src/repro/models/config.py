@@ -21,7 +21,22 @@ class ModelConfig:
     num_experts: int = 0
     experts_per_token: int = 0
     moe_d_ff: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # <= 0: dropless
+    n_shared_experts: int = 0        # always-on experts, one gated MLP of
+                                     # n_shared_experts * moe_d_ff
+    router: str = "softmax"          # softmax | sigmoid (bias-corrected
+                                     # top-k, DeepSeek-V3's noaux_tc)
+    routed_scaling: float = 1.0      # sigmoid router: scale of the weights
+    held_experts: tuple = ()         # (first, count) of the routed experts
+                                     # this chip holds; () holds them all
+    first_dense_layers: int = 0      # leading layers with a dense MLP of
+                                     # d_ff in place of the experts
+
+    # --- latent attention (MLA, DeepSeek-V2); on when kv_lora_rank > 0 ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # --- attention flavour ---
     attention: str = "full"          # full | swa (sliding) | local
@@ -54,6 +69,7 @@ class ModelConfig:
 
     # --- misc ---
     norm: str = "rmsnorm"            # rmsnorm | layernorm
+    norm_eps: float = 1e-6           # rmsnorm's epsilon
     act: str = "silu"                # silu (gated) | gelu (gated) | gelu_plain
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
@@ -67,10 +83,22 @@ class ModelConfig:
     n_classes: int = 0
 
     def __post_init__(self):
+        if self.kv_lora_rank and not self.head_dim:
+            object.__setattr__(self, "head_dim",
+                               self.qk_nope_head_dim + self.qk_rope_head_dim)
         if self.num_heads and not self.head_dim:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
         if self.family in ("hybrid",) and not self.lru_width:
             object.__setattr__(self, "lru_width", self.d_model)
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def experts_here(self) -> tuple:
+        """(first, count) of the routed experts this chip computes."""
+        return self.held_experts or (0, self.num_experts)
 
     @property
     def d_inner(self) -> int:

@@ -46,10 +46,10 @@ def norm_defs(cfg, kind=None):
     return d
 
 
-def apply_norm(p, x, kind="rmsnorm"):
+def apply_norm(p, x, kind="rmsnorm", eps=1e-6):
     if "bias" in p:
         return layernorm(x, p["scale"], p["bias"])
-    return rmsnorm(x, p["scale"])
+    return rmsnorm(x, p["scale"], eps)
 
 
 # --------------------------------------------------------------------------
@@ -101,7 +101,7 @@ PREFILL_DECODE_MARGIN = kvcache.PREFILL_DECODE_MARGIN
 def attention_full(q, k, v, *, causal=True, window=0, q_offset=0):
     """Exact attention with a materialised score matrix. Use for seq <= ~8k.
 
-    q: (B,T,H,D)  k,v: (B,S,Hkv,D).  GQA via head grouping.
+    q: (B,T,H,D)  k: (B,S,Hkv,D)  v: (B,S,Hkv,Dv).  GQA via head grouping.
     """
     B, T, H, D = q.shape
     S, Hkv = k.shape[1], k.shape[2]
@@ -120,7 +120,7 @@ def attention_full(q, k, v, *, causal=True, window=0, q_offset=0):
     scores = jnp.where(mask[None, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bhgts,bshd->bthgd", probs, v)
-    return out.reshape(B, T, H, D)
+    return out.reshape(B, T, H, v.shape[-1])
 
 
 def flash_attention_xla(q, k, v, *, causal=True, window=0, q_offset=0,
@@ -187,7 +187,7 @@ def flash_attention_xla(q, k, v, *, causal=True, window=0, q_offset=0,
 
         m0 = jnp.full((B, Hkv, G, q_block), -jnp.inf, jnp.float32)
         l0 = jnp.zeros((B, Hkv, G, q_block), jnp.float32)
-        a0 = jnp.zeros((B, Hkv, G, q_block, D), jnp.float32)
+        a0 = jnp.zeros((B, Hkv, G, q_block, v.shape[-1]), jnp.float32)
         (m, l, acc), _ = lax.scan(kv_step, (m0, l0, a0), jnp.arange(n_win))
         out = acc / jnp.maximum(l[..., None], 1e-30)
         # (B,Hkv,G,Cq,D) -> (B,Cq,Hkv,G,D)
@@ -195,7 +195,7 @@ def flash_attention_xla(q, k, v, *, causal=True, window=0, q_offset=0,
 
     qblocks = qg.transpose(1, 0, 2, 3, 4, 5)  # (nq,B,Cq,Hkv,G,D)
     _, outs = lax.scan(q_step, None, (qblocks, jnp.arange(nq)))
-    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, T, H, D)
+    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, T, H, v.shape[-1])
     return out
 
 
@@ -258,8 +258,9 @@ def flash_attention_xla_triangular(q, k, v, *, q_offset=0, block=1024):
 
         z = lambda *s_: jnp.zeros(s_, jnp.float32)
         m0 = jnp.full((B, Hkv, G, block), -jnp.inf, jnp.float32)
-        carry0 = (m0, z(B, Hkv, G, block), z(B, Hkv, G, block, D),
-                  m0, z(B, Hkv, G, block), z(B, Hkv, G, block, D))
+        Dv = v.shape[-1]
+        carry0 = (m0, z(B, Hkv, G, block), z(B, Hkv, G, block, Dv),
+                  m0, z(B, Hkv, G, block), z(B, Hkv, G, block, Dv))
         (ma, la, acca, mb, lb, accb), _ = lax.scan(
             kv_step, carry0, jnp.arange(nq + 1))
         outa = (acca / jnp.maximum(la[..., None], 1e-30))
@@ -271,7 +272,7 @@ def flash_attention_xla_triangular(q, k, v, *, q_offset=0, block=1024):
     _, (outs_a, outs_b) = lax.scan(pair_step, None, jnp.arange(nq // 2))
     # outs_a rows: p = 0..nq/2-1; outs_b rows: nq-1-p (descending)
     out = jnp.concatenate([outs_a, outs_b[::-1]], axis=0)
-    out = out.transpose(1, 0, 2, 3, 4, 5).reshape(B, T, H, D)
+    out = out.transpose(1, 0, 2, 3, 4, 5).reshape(B, T, H, v.shape[-1])
     return out
 
 
@@ -573,12 +574,139 @@ attention_cache_defs = kvcache.attention_cache_defs
 
 
 # --------------------------------------------------------------------------
+# Latent attention (MLA, DeepSeek-V2 / V3)
+# --------------------------------------------------------------------------
+#
+# Keys and values come from one latent per token: c = norm(x W_DKV) of
+# kv_lora_rank values, with a rotary key part k_pe = rope(x W_KR) of
+# qk_rope_head_dim values shared by every head.  Per head, k_nope = c W_UK
+# and v = c W_UV.  Train and prefill run this EXPANDED form.  The paged
+# paths keep only the latent row [c || k_pe] per token and layer, and run
+# the ABSORBED form: q_nope W_UK^T scores against c itself, the rope part
+# against k_pe, and the weighted sum of c goes back through W_UV -- the
+# same numbers with the per-head K and V never formed.
+
+def mla_defs(cfg):
+    d, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {
+        "wq": pdef((d, H, dn + dr), ("embed", "heads", None), fan_in_axes=(0,)),
+        "wkv_a": pdef((d, r + dr), ("embed", None), fan_in_axes=(0,)),
+        "kv_norm": {"scale": pdef((r,), (None,), init="ones")},
+        # wkv_b of the published checkpoint, split into its K and V halves
+        "w_uk": pdef((r, H, dn), (None, "heads", None), fan_in_axes=(0,)),
+        "w_uv": pdef((r, H, dv), (None, "heads", None), fan_in_axes=(0,)),
+        "wo": pdef((H, dv, d), ("heads", None, "embed_tp"), fan_in_axes=(0, 1)),
+    }
+
+
+def latent_write(pool, layer, bt, rows, positions):
+    """Scatter latent rows into layer `layer` of the stacked latent pool.
+
+    pool: (L, NB, BS, W); bt: (B, nbmax) block tables; rows: (B, C, W);
+    positions: (B, C) absolute, -1 marking rows whose writes are dropped.
+    Indexing the stacked pool in place (no per-layer slice) lets the
+    scan carry it without copies."""
+    L, nb, bs, W = pool.shape
+    valid = positions >= 0
+    pos = jnp.where(valid, positions, 0)
+    page = jnp.take_along_axis(bt, pos // bs, axis=1)
+    flat = jnp.where(valid, (layer * nb + page) * bs + pos % bs, L * nb * bs)
+    pf = pool.reshape(L * nb * bs, W).at[flat.reshape(-1)].set(
+        rows.reshape(-1, W).astype(pool.dtype), mode="drop")
+    return pf.reshape(pool.shape)
+
+
+def latent_gather(pool, layer, bt):
+    """(B, nbmax * BS, W): the latent rows each table names, of layer
+    `layer`; unallocated entries gather block 0, masked by the caller."""
+    L, nb, bs, W = pool.shape
+    idx = ((layer * nb + bt)[:, :, None] * bs
+           + jnp.arange(bs)[None, None]).reshape(bt.shape[0], -1)
+    return pool.reshape(L * nb * bs, W)[idx]
+
+
+def latent_attention(q, rows, positions, *, scale, dv):
+    """Absorbed MLA attention over gathered latent rows.
+
+    q: (B, C, H, W) latent queries [q_nope W_UK^T || q_pe || 0]; rows:
+    (B, S, W) [c || k_pe || 0] at positions 0..S-1; positions: (B, C), the
+    last key position each query sees.  Returns (B, C, H, dv), the
+    weighted sum of the first dv lanes of the rows (c)."""
+    S = rows.shape[1]
+    s = jnp.einsum("bchw,bsw->bhcs", q, rows,
+                   preferred_element_type=jnp.float32) * scale
+    mask = jnp.arange(S)[None, None, :] <= positions[:, :, None]   # (B,C,S)
+    s = jnp.where(mask[:, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhcs,bsr->bchr", p, rows[..., :dv])
+
+
+def mla_apply(p, cfg, x, positions, *, mode="train", cache=None):
+    """MLA block: expanded in train / prefill; absorbed over the latent
+    pool in chunk_prefill and decode, where `cache` holds the stacked
+    pool ("latent"), this layer's index ("layer"), the block tables
+    ("bt") and, in decode, the lengths ("len").  Returns (out, pool or
+    None)."""
+    B, T, _ = x.shape
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    scale = 1.0 / math.sqrt(dn + dr)
+    q = constrain(jnp.einsum("btd,dhk->bthk", x, p["wq"]),
+                  ("batch", None, "heads", None))
+    q_nope = q[..., :dn]
+    q_pe = rope_apply(q[..., dn:], positions, cfg.rope_theta)
+    kv = jnp.einsum("btd,dr->btr", x, p["wkv_a"])
+    c = rmsnorm(kv[..., :r], p["kv_norm"]["scale"], cfg.norm_eps)
+    k_pe = rope_apply(kv[..., None, r:], positions, cfg.rope_theta)[:, :, 0]
+
+    if cache is None:
+        k_nope = jnp.einsum("btr,rhk->bthk", c, p["w_uk"])
+        v = jnp.einsum("btr,rhk->bthk", c, p["w_uv"])
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe[:, :, None], (B, T, H, dr))], -1)
+        out = select_attention(jnp.concatenate([q_nope, q_pe], -1), k, v,
+                               causal=True)
+        new_pool = None
+    else:
+        if mode == "decode" and "len" not in cache:
+            raise ValueError("MLA decodes through the paged latent pool "
+                             "(PagedServeLoop)")
+        pool, layer, bt = cache["latent"], cache["layer"], cache["bt"]
+        W = pool.shape[-1]
+        pad = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 1)
+                                + [(0, W - a.shape[-1])])
+        wpos = cache["len"][:, None] if mode == "decode" else positions
+        with jax.named_scope("latent_write"):
+            new_pool = latent_write(pool, layer, bt,
+                                    pad(jnp.concatenate([c, k_pe], -1)), wpos)
+        with jax.named_scope("mla_absorb"):
+            q_lat = jnp.einsum("bthk,rhk->bthr", q_nope, p["w_uk"])
+            q_row = pad(jnp.concatenate([q_lat, q_pe.astype(q_lat.dtype)],
+                                        -1))
+        if mode == "decode":
+            # the write comes first: the new token attends to itself
+            from repro.kernels.paged_attention import ops as paged_ops
+            o_lat = paged_ops.paged_latent_attention(
+                q_row[:, 0], new_pool, layer, bt, cache["len"] + 1,
+                scale=scale, dv=r)[:, None]
+        else:
+            o_lat = latent_attention(q_row, latent_gather(new_pool, layer, bt),
+                                     positions, scale=scale, dv=r)
+        with jax.named_scope("mla_absorb"):
+            out = jnp.einsum("bthr,rhk->bthk", o_lat, p["w_uv"])
+    out = constrain(out, ("batch", None, "heads", None))
+    y = jnp.einsum("bthk,hkd->btd", out, p["wo"])
+    return constrain(y, ("batch", None, None)), new_pool
+
+
+# --------------------------------------------------------------------------
 # Dense MLP (gated or plain)
 # --------------------------------------------------------------------------
 
-def mlp_defs(cfg):
+def mlp_defs(cfg, d_ff=None):
     gated = cfg.act in ("silu", "gelu")
-    d, f = cfg.d_model, cfg.d_ff
+    d, f = cfg.d_model, d_ff or cfg.d_ff
     defs = {
         "w_up": pdef((d, f), ("embed", "ffn"), fan_in_axes=(0,)),
         "w_down": pdef((f, d), ("ffn", "embed_tp"), fan_in_axes=(0,)),
@@ -604,17 +732,27 @@ def mlp_apply(p, cfg, x):
 # --------------------------------------------------------------------------
 
 def moe_defs(cfg):
+    """The router keeps every expert's output; only the held experts'
+    weights live here (cfg.experts_here)."""
     d, f, E = cfg.d_model, cfg.moe_d_ff, cfg.num_experts
-    return {
+    Eh = cfg.experts_here[1]
+    defs = {
         "w_router": pdef((d, E), ("embed", None), dtype=jnp.float32,
                          fan_in_axes=(0,)),
-        "w_gate": pdef((E, d, f), ("experts", "embed", "expert_ffn"),
+        "w_gate": pdef((Eh, d, f), ("experts", "embed", "expert_ffn"),
                        fan_in_axes=(1,)),
-        "w_up": pdef((E, d, f), ("experts", "embed", "expert_ffn"),
+        "w_up": pdef((Eh, d, f), ("experts", "embed", "expert_ffn"),
                      fan_in_axes=(1,)),
-        "w_down": pdef((E, f, d), ("experts", "expert_ffn", "embed"),
+        "w_down": pdef((Eh, f, d), ("experts", "expert_ffn", "embed"),
                        fan_in_axes=(1,)),
     }
+    if cfg.router == "sigmoid":
+        # e_score_correction_bias: shifts which experts are chosen, never
+        # their weights
+        defs["e_bias"] = pdef((E,), (None,), dtype=jnp.float32, init="zeros")
+    if cfg.n_shared_experts:
+        defs["shared"] = mlp_defs(cfg, d_ff=cfg.n_shared_experts * f)
+    return defs
 
 
 def moe_capacity(cfg, tokens: int) -> int:
@@ -645,69 +783,105 @@ def _moe_groups(B: int, T: int, min_tokens: int = 2048) -> int:
     return max(g, 1)
 
 
-def moe_apply(p, cfg, x):
-    """Top-k routed expert MLP with per-group capacity + token dropping.
+def moe_route(p, cfg, xg):
+    """(weights (G,ng,k), expert ids (G,ng,k), probs (G,ng,E)) over all E
+    experts.  softmax: top-k of the softmax, renormalised.  sigmoid
+    (DeepSeek-V3 noaux_tc with one group): top-k of sigmoid + e_bias,
+    weighted by the unbiased sigmoid, normalised, times routed_scaling."""
+    logits = jnp.einsum("gnd,de->gne", xg.astype(jnp.float32), p["w_router"])
+    k = cfg.experts_per_token
+    if cfg.router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, gidx = lax.top_k(scores + p["e_bias"], k)
+        gval = jnp.take_along_axis(scores, gidx, axis=-1)
+        gval = gval / (gval.sum(-1, keepdims=True) + 1e-20) * cfg.routed_scaling
+        return gval, gidx, scores / scores.sum(-1, keepdims=True)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gval, gidx = lax.top_k(probs, k)                     # (G,ng,k)
+    gval = gval / jnp.maximum(gval.sum(-1, keepdims=True), 1e-9)
+    return gval, gidx, probs
 
+
+def moe_apply(p, cfg, x):
+    """Top-k routed expert MLP with per-group capacity + token dropping
+    (dropless at capacity_factor <= 0), plus the shared experts.
+
+    The router scores all E experts; only the held ones (cfg.experts_here)
+    are computed, and a choice of an expert held elsewhere adds nothing
+    here -- its part of the result is another chip's.
     Dispatch/combine are GATHERS (memory movement), not one-hot einsums, so
     HLO FLOPs stay proportional to active-expert compute.
     """
     B, T, d = x.shape
     E, k = cfg.num_experts, cfg.experts_per_token
+    lo, Eh = cfg.experts_here
     n = B * T
     G = _moe_groups(B, T)
     ng = n // G
     C = moe_capacity(cfg, ng)
     xg = x.reshape(G, ng, d)
 
-    logits = jnp.einsum("gnd,de->gne", xg.astype(jnp.float32), p["w_router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    gval, gidx = lax.top_k(probs, k)                     # (G,ng,k)
-    gval = gval / jnp.maximum(gval.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("router"):
+        gval, gidx, probs = moe_route(p, cfg, xg)
+    onehot = jax.nn.one_hot(gidx, E, dtype=jnp.int32)    # (G,ng,k,E)
+    if cfg.held_experts:
+        local = gidx - lo
+        held = (local >= 0) & (local < Eh)
+        gidx = jnp.where(held, local, Eh)                # Eh: held elsewhere
+        here = jax.nn.one_hot(gidx, Eh, dtype=jnp.int32)  # (G,ng,k,Eh)
+    else:
+        here = onehot
 
     # Position of each (token, choice) within its expert, per group:
     # slot-major cumsum so choice 0 of token t beats choice 1 of token t.
-    onehot = jax.nn.one_hot(gidx, E, dtype=jnp.int32)    # (G,ng,k,E)
-    flat = onehot.transpose(0, 2, 1, 3).reshape(G, k * ng, E)
+    flat = here.transpose(0, 2, 1, 3).reshape(G, k * ng, Eh)
     pos_flat = jnp.cumsum(flat, axis=1) - flat
-    pos = (pos_flat.reshape(G, k, ng, E).transpose(0, 2, 1, 3)
-           * onehot).sum(-1)                             # (G,ng,k)
+    pos = (pos_flat.reshape(G, k, ng, Eh).transpose(0, 2, 1, 3)
+           * here).sum(-1)                               # (G,ng,k)
     keep = pos < C
+    if cfg.held_experts:
+        keep &= held
 
     # slot_token[g, e, c] = source token index within group (ng == padding)
     gg = jnp.arange(G, dtype=jnp.int32)[:, None]
-    e_flat = jnp.where(keep, gidx, E).reshape(G, -1)
+    e_flat = jnp.where(keep, gidx, Eh).reshape(G, -1)
     c_flat = jnp.where(keep, pos, 0).reshape(G, -1)
     tok = jnp.broadcast_to(jnp.arange(ng, dtype=jnp.int32)[None, :, None],
                            (G, ng, k)).reshape(G, -1)
-    slot_token = jnp.full((G, E + 1, C), ng, jnp.int32)
+    slot_token = jnp.full((G, Eh + 1, C), ng, jnp.int32)
     slot_token = slot_token.at[gg, e_flat, c_flat].set(tok, mode="drop")
-    slot_token = slot_token[:, :E]
+    slot_token = slot_token[:, :Eh]
 
     x_pad = jnp.concatenate([xg, jnp.zeros((G, 1, d), xg.dtype)], axis=1)
     xe = jnp.take_along_axis(
         x_pad[:, :, None, :],                            # (G,ng+1,1,d)
         slot_token.reshape(G, -1)[:, :, None, None], axis=1
-    ).reshape(G, E, C, d)                                # local gather per G
+    ).reshape(G, Eh, C, d)                               # local gather per G
     xe = constrain(xe, ("batch", "experts", None, None))
 
-    g_ = jnp.einsum("gecd,edf->gecf", xe, p["w_gate"])
-    u = jnp.einsum("gecd,edf->gecf", xe, p["w_up"])
-    h = act_fn(cfg.act)(g_) * u
-    ye = jnp.einsum("gecf,efd->gecd", h, p["w_down"])    # (G,E,C,d)
+    with jax.named_scope("routed_experts"):
+        g_ = jnp.einsum("gecd,edf->gecf", xe, p["w_gate"])
+        u = jnp.einsum("gecd,edf->gecf", xe, p["w_up"])
+        h = act_fn(cfg.act)(g_) * u
+        ye = jnp.einsum("gecf,efd->gecd", h, p["w_down"])    # (G,Eh,C,d)
     ye = constrain(ye, ("batch", "experts", None, None))
 
     # combine: gather each token-choice's slot output, weight, sum over k
     ye_flat = jnp.concatenate(
-        [ye.reshape(G, E * C, d), jnp.zeros((G, 1, d), ye.dtype)], axis=1)
-    slot_id = jnp.where(keep, gidx * C + pos, E * C)     # (G,ng,k)
+        [ye.reshape(G, Eh * C, d), jnp.zeros((G, 1, d), ye.dtype)], axis=1)
+    slot_id = jnp.where(keep, gidx * C + pos, Eh * C)    # (G,ng,k)
     yk = jnp.take_along_axis(
         ye_flat[:, :, None, :],
         slot_id.reshape(G, -1)[:, :, None, None], axis=1
     ).reshape(G, ng, k, d)
     y = jnp.einsum("gnkd,gnk->gnd", yk, gval.astype(yk.dtype) * keep)
+    y = y.reshape(B, T, d)
+    if "shared" in p:
+        with jax.named_scope("shared_experts"):
+            y = y + mlp_apply(p["shared"], cfg, x)
     aux = _load_balance_loss(probs.reshape(n, E),
                              onehot.reshape(n, k, E), E, k)
-    return y.reshape(B, T, d), aux
+    return y, aux
 
 
 def _load_balance_loss(probs, onehot, E, k):

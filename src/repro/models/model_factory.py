@@ -47,9 +47,9 @@ class Model:
     @property
     def supports_cache_spec(self) -> bool:
         """CacheSpec layouts (ring / int8) apply to growing KV caches;
-        SSM / RG-LRU state and the enc-dec cross cache keep their own
-        conventions."""
-        return self.cfg.family in ("dense", "moe", "vlm")
+        SSM / RG-LRU state, the enc-dec cross cache and MLA's latent rows
+        keep their own conventions."""
+        return self.cfg.family in ("dense", "moe", "vlm") and not self.cfg.mla
 
     def cache_defs(self, batch: int, seq_len: int, spec=None):
         """Decode-cache defs; `spec` (a models/cache.CacheSpec or its
@@ -65,16 +65,25 @@ class Model:
 
     @property
     def supports_paged_cache(self) -> bool:
-        """Block-table paging applies to growing KV caches (transformer
-        families); SSM/RG-LRU state is O(1) per sequence and the enc-dec
-        cross cache is static, so those keep the contiguous path."""
-        return self.cfg.family in ("dense", "moe", "vlm")
+        """Whether the model's cache defs can be paged: a decoder cache
+        that grows a K/V or latent row per token and keeps every position.
+        SSM/RG-LRU state is O(1) per sequence, the enc-dec cross cache is
+        static and a sliding window keeps a ring, so those keep the
+        contiguous path."""
+        try:
+            self.paged_cache_defs(1, 1, 1, 1)
+        except ValueError:
+            return False
+        return True
 
     def paged_cache_defs(self, batch: int, num_blocks: int, block_size: int,
                          max_blocks_per_seq: int):
-        if not self.supports_paged_cache:
+        """Raises ValueError, with the reason, for a cache that cannot be
+        paged."""
+        if self._cache_defs is not transformer.cache_defs:
             raise ValueError(f"{self.cfg.name}: paged KV cache unsupported "
-                             f"(family={self.cfg.family})")
+                             f"(family={self.cfg.family}: its decode cache "
+                             "does not grow a row per token)")
         return transformer.paged_cache_defs(
             self.cfg, batch, num_blocks, block_size, max_blocks_per_seq)
 

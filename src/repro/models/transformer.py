@@ -7,8 +7,16 @@ Modes:
 
 Named scopes (`jax.named_scope`) mark each part's operations in the
 compiled program's metadata, where a profiler trace finds them: embed,
-attention (with paged_gather inside it on the paged paths), mlp / moe and
-head.  They are metadata only and leave the compiled code as it was.
+attention (with paged_gather inside it on the paged paths; latent_write
+and mla_absorb for latent attention), mlp / moe (router, routed_experts,
+shared_experts) and head.  They are metadata only and leave the compiled
+code as it was.
+
+Latent-attention (MLA) models keep one stacked latent pool (L, NB, BS, W)
+on the paged paths.  It rides the layer scans as a CARRY, each layer
+writing and reading its own slice in place, so that a leading stack of
+dense layers and the MoE stack after it share it without slicing or
+re-concatenating the pool.
 """
 from __future__ import annotations
 
@@ -17,17 +25,19 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.dist.sharding import constrain
+from repro.models import cache as kvcache
 from repro.models import layers as L
 from repro.models.param import pdef, stack_defs, abstract_params
 
 
-def block_defs(cfg):
+def block_defs(cfg, dense=False):
     d = {
         "ln1": L.norm_defs(cfg),
-        "attn": L.attention_defs(cfg),
+        "attn": L.mla_defs(cfg) if cfg.mla else L.attention_defs(cfg),
         "ln2": L.norm_defs(cfg),
     }
-    if cfg.family == "moe" or (cfg.num_experts and cfg.family != "dense"):
+    if not dense and (cfg.family == "moe"
+                      or (cfg.num_experts and cfg.family != "dense")):
         d["moe"] = L.moe_defs(cfg)
     else:
         d["mlp"] = L.mlp_defs(cfg)
@@ -35,37 +45,58 @@ def block_defs(cfg):
 
 
 def lm_defs(cfg):
-    return {
+    """`layers` is the uniform stack; a model with leading dense layers
+    (cfg.first_dense_layers) keeps those in `dense_layers`, applied
+    first."""
+    k = cfg.first_dense_layers
+    if k and not cfg.mla:
+        raise ValueError(f"{cfg.name}: leading dense layers are applied "
+                         "only by the latent-attention layer stacks")
+    defs = {
         "embed": L.embed_defs(cfg),
-        "layers": stack_defs(block_defs(cfg), cfg.num_layers),
+        "layers": stack_defs(block_defs(cfg), cfg.num_layers - k),
         "final_norm": L.norm_defs(cfg),
     }
+    if k:
+        defs["dense_layers"] = stack_defs(block_defs(cfg, dense=True), k)
+    return defs
 
 
 def cache_defs(cfg, batch: int, seq_len: int, spec=None):
     """Decode-cache defs under a CacheSpec (default: cfg.cache_spec).
-    The convention itself lives in models/cache.py."""
-    per_layer = L.attention_cache_defs(cfg, batch, seq_len, spec)
+    The convention itself lives in models/cache.py.  MLA's latent cache
+    takes no spec."""
+    if cfg.mla:
+        per_layer = kvcache.latent_cache_defs(cfg, batch, seq_len)
+    else:
+        per_layer = L.attention_cache_defs(cfg, batch, seq_len, spec)
     return stack_defs(per_layer, cfg.num_layers)
 
 
 def paged_cache_defs(cfg, batch: int, num_blocks: int, block_size: int,
                      max_blocks_per_seq: int):
     """Block-table paged decode cache (see core/paging.py): one KV block
-    pool per layer, shared by all slots, plus per-slot tables/lengths."""
-    per_layer = L.paged_attention_cache_defs(
-        cfg, batch, num_blocks, block_size, max_blocks_per_seq)
+    pool per layer (a latent pool for MLA), shared by all slots, plus
+    per-slot tables/lengths."""
+    if cfg.window:
+        raise ValueError(
+            f"{cfg.name}: sliding-window attention (window={cfg.window}) "
+            "keeps a ring of the last positions; the paged pool does not")
+    fn = (kvcache.paged_latent_cache_defs if cfg.mla
+          else L.paged_attention_cache_defs)
+    per_layer = fn(cfg, batch, num_blocks, block_size, max_blocks_per_seq)
     return stack_defs(per_layer, cfg.num_layers)
 
 
 def _block_apply(p, cfg, x, positions, mode, cache):
     with jax.named_scope("attention"):
-        h = L.apply_norm(p["ln1"], x, cfg.norm)
-        a, new_cache = L.attention_apply(p["attn"], cfg, h, positions,
-                                         mode=mode, cache=cache)
+        h = L.apply_norm(p["ln1"], x, cfg.norm, cfg.norm_eps)
+        attend = L.mla_apply if cfg.mla else L.attention_apply
+        a, new_cache = attend(p["attn"], cfg, h, positions, mode=mode,
+                              cache=cache)
         x = x + a
     with jax.named_scope("moe" if "moe" in p else "mlp"):
-        h = L.apply_norm(p["ln2"], x, cfg.norm)
+        h = L.apply_norm(p["ln2"], x, cfg.norm, cfg.norm_eps)
         if "moe" in p:
             m, aux = L.moe_apply(p["moe"], cfg, h)
         else:
@@ -98,6 +129,10 @@ def lm_apply(params, cfg, batch_inputs, *, mode="train", cache=None):
     else:
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
     bt = batch_inputs.get("block_tables")  # (B, nbmax), chunk_prefill only
+    if cfg.mla:
+        x, aux, new_cache = _latent_layers(params, cfg, x, positions, mode,
+                                           cache, bt)
+        return _head(params, cfg, batch_inputs, x, mode, aux, new_cache)
 
     def body(carry, xs):
         x, aux = carry
@@ -124,7 +159,50 @@ def lm_apply(params, cfg, batch_inputs, *, mode="train", cache=None):
                                        (params["layers"], cache))
     else:
         (x, aux), new_cache = lax.scan(body, (x, 0.0), params["layers"])
+    return _head(params, cfg, batch_inputs, x, mode, aux, new_cache)
 
+
+def _latent_layers(params, cfg, x, positions, mode, cache, bt):
+    """The dense then the MoE stack of an MLA model; on the paged paths
+    the stacked latent pool is carried through both, each layer indexing
+    its own slice.  Returns (x, aux, new cache)."""
+    paged = mode in ("decode", "chunk_prefill")
+    pool = cache["latent"] if paged else None
+    lens = None
+    if mode == "decode":
+        # tables and lengths arrive stacked (L, B, ...); all layers share them
+        bt, lens = cache["bt"][0], cache["len"][0]
+    aux, first = 0.0, 0
+    for name in ("dense_layers", "layers"):
+        if name not in params:
+            continue
+
+        def body(carry, xs):
+            x, aux, pool = carry
+            lp, i = xs
+            lc = None
+            if paged:
+                lc = {"latent": pool, "layer": i, "bt": bt}
+                if lens is not None:
+                    lc["len"] = lens
+            x, pool, a = _block_apply(lp, cfg, x, positions, mode, lc)
+            return (x, aux + a, pool), None
+
+        if cfg.remat and mode == "train":
+            body = jax.checkpoint(body, prevent_cse=False)
+        n = jax.tree.leaves(params[name])[0].shape[0]
+        (x, aux, pool), _ = lax.scan(
+            body, (x, aux, pool),
+            (params[name], first + jnp.arange(n, dtype=jnp.int32)))
+        first += n
+    if mode == "decode":
+        return x, aux, {"latent": pool, "bt": cache["bt"],
+                        "len": cache["len"] + 1}
+    return x, aux, ({"latent": pool} if paged else None)
+
+
+def _head(params, cfg, batch_inputs, x, mode, aux, new_cache):
+    B = x.shape[0]
     with jax.named_scope("head"):
         if mode == "prefill":
             x = x[:, -1:]  # serving needs only the last position's logits
@@ -133,7 +211,7 @@ def lm_apply(params, cfg, batch_inputs, *, mode="train", cache=None):
             # are padded to a bucket length)
             li = batch_inputs["last_index"].reshape(B, 1, 1)
             x = jnp.take_along_axis(x, li, axis=1)
-        x = L.apply_norm(params["final_norm"], x, cfg.norm)
+        x = L.apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
         logits = L.unembed_apply(params["embed"], x)
         logits = constrain(logits, ("batch", None, "vocab"))
     if mode == "train":

@@ -74,6 +74,7 @@ def test_full_config_matches_assignment(arch):
         "recurrentgemma-9b": (38, 4096, 16, 1, 256000),
         "falcon-mamba-7b": (64, 4096, 0, 0, 65024),
         "seamless-m4t-large-v2": (24, 1024, 16, 16, 256206),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 163840),
     }[arch]
     got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
            cfg.vocab_size)
@@ -101,6 +102,7 @@ def test_param_counts_in_expected_range():
         "recurrentgemma-9b": (7.5e9, 11e9),
         "falcon-mamba-7b": (6e9, 8.5e9),
         "seamless-m4t-large-v2": (0.8e9, 1.8e9),
+        "moonlight-16b-a3b": (15.5e9, 16.5e9),
     }
     for arch, (lo, hi) in expect.items():
         n = build_model(get_config(arch)).n_params

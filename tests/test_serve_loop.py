@@ -142,7 +142,7 @@ def test_paged_preemption_requeues_exactly():
 def test_paged_rejects_stateful_families():
     cfg = get_smoke_config("falcon-mamba-7b")
     model = build_model(cfg)
-    with pytest.raises(AssertionError, match="paged"):
+    with pytest.raises(ValueError, match="paged"):
         PagedServeLoop(model, model.init(jax.random.key(0)))
 
 

@@ -158,3 +158,55 @@ def test_paged_decode_step_reads_the_pool_through_the_kernel(one_chip,
                      text)
     gathered = f"f32[{B * NB * BS},{cfg.num_kv_heads},{cfg.head_dim}]"
     assert gathered not in text
+
+
+@pytest.mark.parametrize("B,H,W,BS", [
+    (64, 16, 640, 16),    # moonlight-16b-a3b: 576-value rows in 640 lanes
+    (8, 8, 256, 32),
+])
+def test_paged_latent_attention_kernel_compiles_for_v5e(one_chip, B, H, W,
+                                                       BS):
+    from repro.kernels.paged_attention.latent import (NAME,
+                                                      paged_latent_attention)
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                  sharding=one_chip)
+    step = jax.jit(functools.partial(paged_latent_attention, scale=0.07,
+                                     dv=W - 128, interpret=False))
+    text = step.lower(S((B, H, W), jnp.bfloat16),
+                      S((27, 512, BS, W), jnp.bfloat16), S((), jnp.int32),
+                      S((B, 160), jnp.int32), S((B,), jnp.int32)
+                      ).compile().as_text()
+    assert NAME in text and "tpu_custom_call" in text
+
+
+def test_latent_decode_step_carries_the_pool_in_place(one_chip, monkeypatch):
+    """PagedServeLoop's decode step for moonlight-16b-a3b's chip share (27
+    layers, 8 held experts, a 1024 x 16 latent pool, batch 64), compiled
+    for a v5e: attention is the named latent kernel, and the stacked pool
+    is never copied (the layer scans carry it and write it in place)."""
+    from repro.configs.moonlight_16b_a3b import CHIP_SHARE as cfg
+    from repro.kernels.paged_attention import ops
+    from repro.launch.serve_loop import PagedServeLoop
+    from repro.models import build_model
+    from repro.models.cache import latent_lanes
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    model = build_model(cfg)
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(model.init,
+                                                  jax.random.key(0)))
+    B, NB, BS = 64, 1024, 16
+    shape = (cfg.num_layers, NB, BS, latent_lanes(cfg))
+    pool = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    i32 = lambda s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    loop = PagedServeLoop.__new__(PagedServeLoop)
+    loop.model, loop.rules = model, None
+    compiled = jax.jit(loop._decode_impl, donate_argnums=(1,)).lower(
+        params, {"latent": pool}, i32((B, 160)), i32((B, 1)),
+        i32((B, 1))).compile()
+    text = compiled.as_text()
+    assert re.search(r"%paged_latent_attention(\.\d+)? = .*custom-call\(",
+                     text)
+    pool_type = "bf16[" + ",".join(map(str, shape)) + "]"
+    assert not re.search(re.escape(pool_type) + r"\{[^}]*\} copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
