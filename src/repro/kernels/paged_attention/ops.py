@@ -21,15 +21,17 @@ def _on_tpu() -> bool:
 def kernel_fits(q, kp) -> bool:
     """The kernel's tiling needs lane-wide heads, whole bf16 tiles per
     block, and a bf16 pool."""
-    D, BS = q.shape[-1], kp.shape[1]
+    D, BS = q.shape[-1], kp.shape[3]
     return (D % LANES == 0 and BS % SUBLANES == 0
             and kp.dtype == jnp.bfloat16)
 
 
-def paged_decode_attention(q, kp, vp, bt, lengths, *, impl: str = "auto"):
-    """q: (B, 1, H, D) over the (NB, BS, Hkv, D) pools kp/vp through the
-    (B, nbmax) block tables bt; lengths (B,) counts the positions each
-    slot attends (0 for a free slot, whose output is not read).
+def paged_decode_attention(q, kp, vp, layer, bt, lengths, *,
+                           impl: str = "auto"):
+    """q: (B, 1, H, D) over layer `layer` of the stacked (L, NB, Hkv, BS,
+    D) pools kp/vp through the (B, nbmax) block tables bt; lengths (B,)
+    counts the positions each slot attends (0 for a free slot, whose
+    output is not read).
 
     impl="auto" runs the kernel on a TPU when `kernel_fits`, the
     reference otherwise; "pallas" (interpret mode off the TPU) and "ref"
@@ -38,8 +40,8 @@ def paged_decode_attention(q, kp, vp, bt, lengths, *, impl: str = "auto"):
     if impl == "auto":
         impl = "pallas" if _on_tpu() and kernel_fits(q, kp) else "ref"
     if impl == "ref":
-        return paged_decode_attention_ref(q, kp, vp, bt, lengths)
-    return kernel.paged_decode_attention(q, kp, vp, bt, lengths,
+        return paged_decode_attention_ref(q, kp, vp, layer, bt, lengths)
+    return kernel.paged_decode_attention(q, kp, vp, layer, bt, lengths,
                                          interpret=not _on_tpu())
 
 

@@ -162,11 +162,13 @@ def paged_attention_cache_defs(cfg, batch, num_blocks, block_size,
     """Abstract paged-cache leaves (per layer): one block POOL shared by
     ALL sequences plus per-slot block tables and lengths.  Unlike the
     contiguous cache, HBM scales with the pool (total tokens resident),
-    not max_batch * max_len.  The pool is bf16 + head-sharded only (the
-    block dim hosts scatter writes, which GSPMD cannot shard without
-    gathering the pool)."""
-    kv = (num_blocks, block_size, cfg.num_kv_heads, cfg.head_dim)
-    ax = (None, None, "kv_heads", None)
+    not max_batch * max_len.  A block is head-major, (Hkv, BS, D): each
+    head's BS rows are whole bf16 tiles, so the pool is stored unpadded
+    and in the order the decode kernel reads it.  The pool is bf16 +
+    head-sharded only (the block dim hosts scatter writes, which GSPMD
+    cannot shard without gathering the pool)."""
+    kv = (num_blocks, cfg.num_kv_heads, block_size, cfg.head_dim)
+    ax = (None, "kv_heads", None, None)
     return {
         "kp": pdef(kv, ax, dtype=jnp.bfloat16, init="zeros"),
         "vp": pdef(kv, ax, dtype=jnp.bfloat16, init="zeros"),

@@ -336,53 +336,74 @@ def ring_decode_attention(q, k_cache, v_cache, cache_len, *, segments):
     return out.reshape(B, 1, H, D)
 
 
-def paged_kv_write(kp, vp, bt, kk, vv, positions):
-    """Scatter per-token K/V into the paged pool.
+def paged_kv_write(kp, vp, layer, bt, kk, vv, positions):
+    """Write per-token K/V into layer `layer` of the stacked paged pool,
+    whole blocks at a time.
 
-    kp/vp: (NB, BS, Hkv, D) block pool shared by ALL sequences;
-    bt: (B, nbmax) block tables; kk/vv: (B, C, Hkv, D) new K/V;
-    positions: (B, C) ABSOLUTE positions, -1 marking padding rows whose
-    writes are dropped (bucketed prefill pads the tail chunk).
+    kp/vp: (L, NB, Hkv, BS, D) block pools shared by ALL sequences;
+    layer: int32 scalar; bt: (B, nbmax) block tables; kk/vv: (B, C, Hkv,
+    D) new K/V; positions: (B, C) ABSOLUTE positions, consecutive from a
+    row's first, -1 marking padding rows whose writes are dropped
+    (bucketed prefill pads the tail chunk; a free decode slot is all -1).
 
-    Distinct sequences write distinct blocks by construction (shared
-    prefix blocks are read-only: the allocator only shares full prompt
-    blocks, and writes happen at positions >= the private tail), so the
-    scatter is collision-free.
+    C consecutive positions span at most `nt` blocks.  Those blocks are
+    read, the new rows merged in at their offsets, and the blocks written
+    back whole into the layer's slice in place: a scatter over the block
+    axis only, which keeps the head-major layout, so a layer scan can
+    carry the stacked pool without relaying it.  A block no row writes is
+    dropped from the scatter.  Distinct sequences write distinct blocks
+    by construction (shared prefix blocks are read-only: the allocator
+    only shares full prompt blocks, and writes happen at positions >= the
+    private tail), so the scatter is collision-free.
     """
-    nb, bs = kp.shape[0], kp.shape[1]
-    valid = positions >= 0
-    pos = jnp.where(valid, positions, 0)
-    page = jnp.take_along_axis(bt, pos // bs, axis=1)          # (B, C)
-    flat = jnp.where(valid, page * bs + pos % bs, nb * bs)     # OOB drops
-    flat = flat.reshape(-1)
-    kf = kp.reshape(nb * bs, *kp.shape[2:])
-    vf = vp.reshape(nb * bs, *vp.shape[2:])
-    kf = kf.at[flat].set(
-        kk.reshape(-1, *kk.shape[2:]).astype(kp.dtype), mode="drop")
-    vf = vf.at[flat].set(
-        vv.reshape(-1, *vv.shape[2:]).astype(vp.dtype), mode="drop")
-    return kf.reshape(kp.shape), vf.reshape(vp.shape)
+    nb, bs = kp.shape[1], kp.shape[3]
+    B, C = positions.shape
+    nt = -(-(C - 1) // bs) + 1              # blocks C consecutive rows span
+    first = positions[:, :1]
+    blk = first // bs + jnp.arange(nt)                          # (B, nt)
+    # the position each row of each touched block holds, and the row of
+    # kk / vv that writes it, if any
+    pos = (blk[:, :, None] * bs + jnp.arange(bs)).reshape(B, nt * bs)
+    src = pos - first
+    row = jnp.clip(src, 0, C - 1)
+    keep = ((src == row) & (pos >= 0)
+            & (jnp.take_along_axis(positions, row, axis=1) == pos)
+            ).reshape(B, nt, bs)
+    page = jnp.take_along_axis(bt, jnp.clip(blk, 0, bt.shape[1] - 1), axis=1)
+    dest = jnp.where(keep.any(-1), page, nb)                    # nb: dropped
+
+    def merge(pool, new):
+        old = pool[layer, jnp.minimum(dest, nb - 1)]        # (B,nt,Hkv,BS,D)
+        rows = jnp.take_along_axis(new, row[:, :, None, None], axis=1)
+        rows = rows.reshape(B, nt, bs, *new.shape[2:]).swapaxes(2, 3)
+        blocks = jnp.where(keep[:, :, None, :, None],
+                           rows.astype(pool.dtype), old)
+        return pool.at[layer, dest].set(blocks, mode="drop")
+
+    return merge(kp, kk), merge(vp, vv)
 
 
-def paged_gather_kv(kp, vp, bt):
-    """Gather each sequence's K/V view from the block pool.
+def paged_gather_kv(kp, vp, layer, bt):
+    """Gather each sequence's K/V view from layer `layer` of the stacked
+    (L, NB, Hkv, BS, D) block pools.
 
-    Returns (B, nbmax*BS, Hkv, D) -- unallocated table entries (0-filled)
-    gather block 0's contents; callers mask by sequence length so the
-    garbage never contributes attention weight.
+    Returns head-major (B, Hkv, nbmax*BS, D) -- unallocated table entries
+    (0-filled) gather block 0's contents; callers mask by sequence length
+    so the garbage never contributes attention weight.
     """
-    nb, bs = kp.shape[0], kp.shape[1]
     B, nbmax = bt.shape
-    idx = (bt[:, :, None] * bs + jnp.arange(bs)[None, None]).reshape(B, -1)
-    kf = kp.reshape(nb * bs, *kp.shape[2:])
-    vf = vp.reshape(nb * bs, *vp.shape[2:])
-    return kf[idx], vf[idx]
+
+    def view(pool):
+        g = pool[layer, bt].swapaxes(1, 2)         # (B, Hkv, nbmax, BS, D)
+        return g.reshape(B, g.shape[1], nbmax * g.shape[3], g.shape[4])
+
+    return view(kp), view(vp)
 
 
 def paged_chunk_attention(q, k_seq, v_seq, positions):
     """Exact causal attention of a prefill CHUNK over the paged view.
 
-    q: (B, C, H, D) chunk queries; k_seq/v_seq: (B, S, Hkv, D) gathered
+    q: (B, C, H, D) chunk queries; k_seq/v_seq: (B, Hkv, S, D) gathered
     pages (already containing this chunk's K/V *and* any shared-prefix
     blocks); positions: (B, C) absolute query positions (-1 = padding;
     such rows attend to nothing real and their output is discarded).
@@ -390,17 +411,17 @@ def paged_chunk_attention(q, k_seq, v_seq, positions):
     bounded-size chunks.
     """
     B, C, H, D = q.shape
-    S, Hkv = k_seq.shape[1], k_seq.shape[2]
+    Hkv, S = k_seq.shape[1], k_seq.shape[2]
     G = H // Hkv
     qg = q.reshape(B, C, Hkv, G, D)
     scale = 1.0 / math.sqrt(D)
-    s = jnp.einsum("bthgd,bshd->bhgts", qg, k_seq,
+    s = jnp.einsum("bthgd,bhsd->bhgts", qg, k_seq,
                    preferred_element_type=jnp.float32) * scale
     kpos = jnp.arange(S)
     mask = kpos[None, None, :] <= positions[:, :, None]        # (B, C, S)
     s = jnp.where(mask[:, None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhgts,bshd->bthgd", p, v_seq)
+    out = jnp.einsum("bhgts,bhsd->bthgd", p, v_seq)
     return out.reshape(B, C, H, D)
 
 
@@ -495,15 +516,16 @@ def attention_apply(p, cfg, x, positions, *, mode="train", cache=None,
 
     new_cache = cache
     if mode == "chunk_prefill" and cache is not None and "kp" in cache:
-        # paged chunked prefill: scatter this chunk's K/V into the block
-        # pool, then exact attention over the sequence's gathered view
-        # (which already holds any shared-prefix blocks -- their
-        # positions are simply never re-computed).
+        # paged chunked prefill: write this chunk's K/V into the layer's
+        # blocks of the stacked pool, then exact attention over the
+        # sequence's gathered view (which already holds any shared-prefix
+        # blocks -- their positions are simply never re-computed).
         assert not window, "paged cache does not support sliding windows"
+        layer, bt = cache["layer"], cache["bt"]
         with jax.named_scope("paged_gather"):
-            kp, vp = paged_kv_write(cache["kp"], cache["vp"], cache["bt"],
+            kp, vp = paged_kv_write(cache["kp"], cache["vp"], layer, bt,
                                     kk, vv, positions)
-            k_seq, v_seq = paged_gather_kv(kp, vp, cache["bt"])
+            k_seq, v_seq = paged_gather_kv(kp, vp, layer, bt)
         out = paged_chunk_attention(q, k_seq, v_seq, positions)
         new_cache = {"kp": kp, "vp": vp}
     elif mode == "chunk_prefill":
@@ -525,14 +547,15 @@ def attention_apply(p, cfg, x, positions, *, mode="train", cache=None,
                                window=window, q_offset=cache_len[0])
     elif mode == "decode" and "kp" in cache:
         assert not window, "paged cache does not support sliding windows"
-        kp, vp, bt = cache["kp"], cache["vp"], cache["bt"]
-        cache_len = cache["len"]
+        layer, bt, cache_len = cache["layer"], cache["bt"], cache["len"]
         with jax.named_scope("paged_gather"):
-            kp, vp = paged_kv_write(kp, vp, bt, kk, vv, cache_len[:, None])
+            kp, vp = paged_kv_write(cache["kp"], cache["vp"], layer, bt,
+                                    kk, vv, cache_len[:, None])
         # the write comes first: the new token attends to itself
         from repro.kernels.paged_attention import ops as paged_ops
-        out = paged_ops.paged_decode_attention(q, kp, vp, bt, cache_len + 1)
-        new_cache = {"kp": kp, "vp": vp, "bt": bt, "len": cache_len + 1}
+        out = paged_ops.paged_decode_attention(q, kp, vp, layer, bt,
+                                               cache_len + 1)
+        new_cache = {"kp": kp, "vp": vp}
     elif mode == "decode":
         spec = kvcache.spec_of(cfg)
         cache_len = cache["len"]
@@ -646,8 +669,8 @@ def mla_apply(p, cfg, x, positions, *, mode="train", cache=None):
     """MLA block: expanded in train / prefill; absorbed over the latent
     pool in chunk_prefill and decode, where `cache` holds the stacked
     pool ("latent"), this layer's index ("layer"), the block tables
-    ("bt") and, in decode, the lengths ("len").  Returns (out, pool or
-    None)."""
+    ("bt") and, in decode, the lengths ("len").  Returns (out, {"latent":
+    pool} or None)."""
     B, T, _ = x.shape
     H, r = cfg.num_heads, cfg.kv_lora_rank
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
@@ -669,9 +692,6 @@ def mla_apply(p, cfg, x, positions, *, mode="train", cache=None):
                                causal=True)
         new_pool = None
     else:
-        if mode == "decode" and "len" not in cache:
-            raise ValueError("MLA decodes through the paged latent pool "
-                             "(PagedServeLoop)")
         pool, layer, bt = cache["latent"], cache["layer"], cache["bt"]
         W = pool.shape[-1]
         pad = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 1)
@@ -695,6 +715,7 @@ def mla_apply(p, cfg, x, positions, *, mode="train", cache=None):
                                      positions, scale=scale, dv=r)
         with jax.named_scope("mla_absorb"):
             out = jnp.einsum("bthr,rhk->bthk", o_lat, p["w_uv"])
+        new_pool = {"latent": new_pool}
     out = constrain(out, ("batch", None, "heads", None))
     y = jnp.einsum("bthk,hkd->btd", out, p["wo"])
     return constrain(y, ("batch", None, None)), new_pool

@@ -12,11 +12,14 @@ and mla_absorb for latent attention), mlp / moe (router, routed_experts,
 shared_experts) and head.  They are metadata only and leave the compiled
 code as it was.
 
-Latent-attention (MLA) models keep one stacked latent pool (L, NB, BS, W)
-on the paged paths.  It rides the layer scans as a CARRY, each layer
-writing and reading its own slice in place, so that a leading stack of
-dense layers and the MoE stack after it share it without slicing or
-re-concatenating the pool.
+On the paged paths (decode, and chunk_prefill with block tables) the
+stacked block pool rides the layer scans as a CARRY: the K/V pools
+{kp, vp} of (L, NB, Hkv, BS, D), or a latent-attention (MLA) model's
+latent pool (L, NB, BS, W).  Each layer writes and reads its own slice
+in place, by its index, so the pool is never sliced per layer or
+re-stacked, and a leading stack of dense layers and the stack after it
+share it.  The contiguous cache (ServeLoop, the chunked prefill step)
+still rides the scan as xs, sliced and re-stacked per layer.
 """
 from __future__ import annotations
 
@@ -49,9 +52,6 @@ def lm_defs(cfg):
     (cfg.first_dense_layers) keeps those in `dense_layers`, applied
     first."""
     k = cfg.first_dense_layers
-    if k and not cfg.mla:
-        raise ValueError(f"{cfg.name}: leading dense layers are applied "
-                         "only by the latent-attention layer stacks")
     defs = {
         "embed": L.embed_defs(cfg),
         "layers": stack_defs(block_defs(cfg), cfg.num_layers - k),
@@ -129,50 +129,30 @@ def lm_apply(params, cfg, batch_inputs, *, mode="train", cache=None):
     else:
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
     bt = batch_inputs.get("block_tables")  # (B, nbmax), chunk_prefill only
-    if cfg.mla:
-        x, aux, new_cache = _latent_layers(params, cfg, x, positions, mode,
-                                           cache, bt)
-        return _head(params, cfg, batch_inputs, x, mode, aux, new_cache)
-
-    def body(carry, xs):
-        x, aux = carry
-        if mode in ("decode", "chunk_prefill"):
-            lp, lc = xs
-        else:
-            lp, lc = xs, None
-        if mode == "chunk_prefill" and bt is not None:
-            lc = {**lc, "bt": bt}
-        x, new_cache, a = _block_apply(lp, cfg, x, positions, mode, lc)
-        if mode == "chunk_prefill" and bt is not None:
-            # paged: bt rides in batch_inputs, only the pool is carried;
-            # the CONTIGUOUS chunked path (no block tables) carries the
-            # whole spec'd cache {k, v, (scales,) len} like decode does
-            new_cache = {k: new_cache[k] for k in ("kp", "vp")}
-        return (x, aux + a), new_cache
-
-    if cfg.remat and mode == "train":
-        body = jax.checkpoint(body, prevent_cse=False)
-
-    if mode in ("decode", "chunk_prefill"):
-        # cache leaves are stacked (L, ...): per-layer slices ride the scan.
-        (x, aux), new_cache = lax.scan(body, (x, 0.0),
-                                       (params["layers"], cache))
+    if (mode == "decode" and "bt" not in cache) or (
+            mode == "chunk_prefill" and bt is None):
+        x, aux, new_cache = _contiguous_layers(params, cfg, x, positions,
+                                               mode, cache)
     else:
-        (x, aux), new_cache = lax.scan(body, (x, 0.0), params["layers"])
+        x, aux, new_cache = _layers(params, cfg, x, positions, mode, cache,
+                                    bt)
     return _head(params, cfg, batch_inputs, x, mode, aux, new_cache)
 
 
-def _latent_layers(params, cfg, x, positions, mode, cache, bt):
-    """The dense then the MoE stack of an MLA model; on the paged paths
-    the stacked latent pool is carried through both, each layer indexing
-    its own slice.  Returns (x, aux, new cache)."""
+def _layers(params, cfg, x, positions, mode, cache, bt):
+    """The dense then the uniform stack.  On the paged paths the stacked
+    pools ({kp, vp} or {latent}, as the defs give them) are carried
+    through both, each layer indexing its own slice; in train / prefill
+    the per-layer caches (prefill's K/V, or None) are stacked as the scan
+    returns them.  Returns (x, aux, new cache)."""
     paged = mode in ("decode", "chunk_prefill")
-    pool = cache["latent"] if paged else None
+    pool = ({k: v for k, v in cache.items() if k not in ("bt", "len")}
+            if paged else None)
     lens = None
     if mode == "decode":
         # tables and lengths arrive stacked (L, B, ...); all layers share them
         bt, lens = cache["bt"][0], cache["len"][0]
-    aux, first = 0.0, 0
+    aux, first, stacks = 0.0, 0, []
     for name in ("dense_layers", "layers"):
         if name not in params:
             continue
@@ -182,23 +162,48 @@ def _latent_layers(params, cfg, x, positions, mode, cache, bt):
             lp, i = xs
             lc = None
             if paged:
-                lc = {"latent": pool, "layer": i, "bt": bt}
+                lc = {**pool, "layer": i, "bt": bt}
                 if lens is not None:
                     lc["len"] = lens
-            x, pool, a = _block_apply(lp, cfg, x, positions, mode, lc)
-            return (x, aux + a, pool), None
+            x, new, a = _block_apply(lp, cfg, x, positions, mode, lc)
+            if paged:
+                return (x, aux + a, new), None
+            return (x, aux + a, pool), new
 
         if cfg.remat and mode == "train":
             body = jax.checkpoint(body, prevent_cse=False)
         n = jax.tree.leaves(params[name])[0].shape[0]
-        (x, aux, pool), _ = lax.scan(
+        (x, aux, pool), ys = lax.scan(
             body, (x, aux, pool),
             (params[name], first + jnp.arange(n, dtype=jnp.int32)))
+        stacks.append(ys)
         first += n
     if mode == "decode":
-        return x, aux, {"latent": pool, "bt": cache["bt"],
-                        "len": cache["len"] + 1}
-    return x, aux, ({"latent": pool} if paged else None)
+        return x, aux, {**pool, "bt": cache["bt"], "len": cache["len"] + 1}
+    if paged:
+        return x, aux, pool
+    if len(stacks) == 1:
+        return x, aux, stacks[0]
+    return x, aux, jax.tree.map(lambda *a: jnp.concatenate(a), *stacks)
+
+
+def _contiguous_layers(params, cfg, x, positions, mode, cache):
+    """Decode / chunked prefill over the CONTIGUOUS spec'd cache {k, v,
+    (scales,) len}, stacked (L, ...): each layer's slice rides the scan as
+    xs and is re-stacked.  Returns (x, aux, new cache)."""
+    if cfg.mla or "dense_layers" in params:
+        raise ValueError(f"{cfg.name}: the contiguous cache covers a "
+                         "uniform stack of K/V layers; this model decodes "
+                         "through the paged pool (PagedServeLoop)")
+
+    def body(carry, xs):
+        x, aux = carry
+        lp, lc = xs
+        x, new_cache, a = _block_apply(lp, cfg, x, positions, mode, lc)
+        return (x, aux + a), new_cache
+
+    (x, aux), new_cache = lax.scan(body, (x, 0.0), (params["layers"], cache))
+    return x, aux, new_cache
 
 
 def _head(params, cfg, batch_inputs, x, mode, aux, new_cache):

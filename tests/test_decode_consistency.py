@@ -63,15 +63,22 @@ def test_prefill_then_decode_matches_teacher_forcing(arch):
     np.testing.assert_allclose(got, ref_last, rtol=2e-2, atol=2e-2)
 
 
-@pytest.mark.parametrize("arch", ["granite-20b", "chatglm3-6b"])
-def test_paged_chunk_prefill_then_decode_matches_teacher_forcing(arch):
+@pytest.mark.parametrize("arch,dense", [("granite-20b", 0),
+                                        ("chatglm3-6b", 0),
+                                        ("qwen3-moe-235b-a22b", 1)],
+                         ids=["granite-20b", "chatglm3-6b",
+                              "qwen3-moe-235b-a22b-dense1"])
+def test_paged_chunk_prefill_then_decode_matches_teacher_forcing(arch, dense):
     """Block-table paged path at the LOGITS level: chunked/bucketed
     prefill through the block pool, then paged decode steps, must match
     teacher forcing like the contiguous path does (granite = MQA,
-    chatglm = GQA + partial rope + qkv bias)."""
+    chatglm = GQA + partial rope + qkv bias; the MoE model with a leading
+    dense layer, whose two layer stacks share the carried pool)."""
+    import dataclasses
     from repro.models.param import is_def
 
-    cfg = get_smoke_config(arch)
+    cfg = dataclasses.replace(get_smoke_config(arch),
+                              first_dense_layers=dense)
     model = build_model(cfg)
     params = model.init(jax.random.key(7))
     B, T, extra = 1, 13, 3

@@ -107,7 +107,7 @@ def test_quant8_kernels_carry_their_names(one_chip, shape):
 
 
 @pytest.mark.parametrize("B,H,Hkv,BS", [
-    (8, 20, 20, 16),      # qwen1.5-4b: 20 KV heads, stored in 24 rows
+    (8, 20, 20, 16),      # qwen1.5-4b: 20 KV heads
     (8, 32, 2, 16),       # chatglm3-6b: 16 query heads per KV head
     (4, 16, 4, 32),
 ])
@@ -116,12 +116,12 @@ def test_paged_attention_kernel_compiles_for_v5e(one_chip, B, H, Hkv, BS):
                                                       paged_decode_attention)
     S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                   sharding=one_chip)
-    pool = S((512, BS, Hkv, 128), jnp.bfloat16)
+    pool = S((40, 512, Hkv, BS, 128), jnp.bfloat16)
     step = jax.jit(functools.partial(paged_decode_attention,
                                      interpret=False))
     text = step.lower(S((B, 1, H, 128), jnp.bfloat16), pool, pool,
-                      S((B, 512), jnp.int32), S((B,), jnp.int32)
-                      ).compile().as_text()
+                      S((), jnp.int32), S((B, 512), jnp.int32),
+                      S((B,), jnp.int32)).compile().as_text()
     assert NAME in text and "tpu_custom_call" in text
 
 
@@ -144,7 +144,7 @@ def test_paged_decode_step_reads_the_pool_through_the_kernel(one_chip,
                                                   jax.random.key(0)))
     B, NB, BS = 8, 512, 16
     pool = jax.ShapeDtypeStruct(
-        (cfg.num_layers, NB, BS, cfg.num_kv_heads, cfg.head_dim),
+        (cfg.num_layers, NB, cfg.num_kv_heads, BS, cfg.head_dim),
         jnp.bfloat16, sharding=one_chip)
     i32 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32,
                                              sharding=one_chip)
@@ -158,6 +158,52 @@ def test_paged_decode_step_reads_the_pool_through_the_kernel(one_chip,
                      text)
     gathered = f"f32[{B * NB * BS},{cfg.num_kv_heads},{cfg.head_dim}]"
     assert gathered not in text
+
+
+@pytest.mark.parametrize("step,temp_limit", [("_decode_impl", 64 << 20),
+                                             ("_chunk_impl", 128 << 20)])
+def test_paged_steps_carry_the_pool_in_place(one_chip, monkeypatch, step,
+                                             temp_limit):
+    """PagedServeLoop's decode and chunk-prefill steps at qwen1.5-4b's
+    widths (40 layers, a 512 x 16 pool, batch 8, chunks of 64), compiled
+    for a v5e: the layer scan carries the stacked head-major pool and
+    writes whole blocks into it, so the pool is never copied, no layer's
+    pool is sliced out, and the temporaries hold no second pool."""
+    from repro.configs import get_config
+    from repro.kernels.paged_attention import ops
+    from repro.launch.serve_loop import PagedServeLoop
+    from repro.models import build_model
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = get_config("qwen1.5-4b")
+    model = build_model(cfg)
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(model.init,
+                                                  jax.random.key(0)))
+    B, NB, BS, C = 8, 512, 16, 64
+    layer_pool = (NB, cfg.num_kv_heads, BS, cfg.head_dim)
+    shape = (cfg.num_layers,) + layer_pool
+    pool = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    i32 = lambda s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    loop = PagedServeLoop.__new__(PagedServeLoop)
+    loop.model, loop.rules = model, None
+    pages = {"kp": pool, "vp": pool}
+    if step == "_decode_impl":
+        args = (params, pages, i32((B, NB)), i32((B, 1)), i32((B, 1)))
+    else:
+        args = (params, pages, i32((1, C)), i32((1, C)), i32((1, NB)),
+                i32((1,)))
+    compiled = jax.jit(getattr(loop, step), donate_argnums=(1,)).lower(
+        *args).compile()
+    text = compiled.as_text()
+    typed = lambda s: re.escape("bf16[" + ",".join(map(str, s)) + "]")
+    assert not re.search(typed(shape) + r"\{[^}]*\} copy\(", text)
+    assert not re.search(typed(layer_pool) + r"\{[^}]*\} dynamic-slice\(",
+                         text)
+    if step == "_decode_impl":
+        assert re.search(
+            r"%paged_decode_attention(\.\d+)? = .*custom-call\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
 
 
 @pytest.mark.parametrize("B,H,W,BS", [
