@@ -12,14 +12,17 @@ Two cache disciplines behind one Request/submit/tick API:
 
 * ``PagedServeLoop`` -- the BLOCK-TABLE PAGED cache (transformer
   families): one KV block pool shared by all slots (core/paging.py
-  allocator: free list, refcounts, prefix sharing), per-slot block
-  tables mapping position -> (block, offset), chunked+bucketed prefill
-  so any prompt length streams through a bounded number of jit cache
-  entries, lazy block growth during decode, and preemption (requeue the
-  youngest sequence) when the pool runs dry.  Greedy decode is
-  token-identical to ServeLoop (tests/test_serve_loop.py).  Its tick
-  records host spans (`flight.serve.*`, repro/trace.py) around each
-  phase, and `counters` counts the work where it happens.
+  allocator: free list, refcounts, prefix sharing) is the whole device
+  cache.  Per-slot block tables, mapping position -> (block, offset),
+  and positions live on the host and go with every decode and prefill
+  step as the model's `block_tables` and `positions` inputs.  Chunked,
+  bucketed prefill streams any prompt length through a bounded number
+  of jit cache entries; blocks grow lazily during decode, and the
+  youngest sequence is preempted (requeued) when the pool runs dry.
+  Greedy decode is token-identical to ServeLoop
+  (tests/test_serve_loop.py).  Its tick records host spans
+  (`flight.serve.*`, repro/trace.py) around each phase, and `counters`
+  counts the work where it happens.
 
 CPU-runnable at smoke scale; the same loops drive TPU serving, with the
 weight layout (stationary / hybrid / fsdp) picked per model by the
@@ -219,8 +222,7 @@ class PagedServeLoop(_ServeBase):
         nbmax = num_blocks if max_seq_len is None else min(
             num_blocks, -(-max_seq_len // block_size))
         # raises ValueError for a model whose cache cannot be paged
-        defs = model.paged_cache_defs(max_batch, num_blocks, block_size,
-                                      nbmax)
+        defs = model.paged_cache_defs(num_blocks, block_size)
         super().__init__(model, params, max_batch=max_batch, layout=layout)
         self.alloc = BlockAllocator(num_blocks, block_size)
         self.bs = block_size
@@ -228,11 +230,10 @@ class PagedServeLoop(_ServeBase):
         self.max_seq_len = max_seq_len
         self.chunk = chunk
         from repro.models.param import is_def
-        full = jax.tree.map(lambda d: jnp.zeros(d.shape, d.dtype), defs,
-                            is_leaf=is_def)
         # only the block pool lives on device between ticks; tables and
-        # lengths are rebuilt from host truth every step
-        self.pages = {k: v for k, v in full.items() if k not in ("bt", "len")}
+        # positions go with every step, from host truth
+        self.pages = jax.tree.map(lambda d: jnp.zeros(d.shape, d.dtype),
+                                  defs, is_leaf=is_def)
         self.bt = np.zeros((max_batch, self.nbmax), np.int32)
         self._seq_of_slot: dict[int, int] = {}
         self._admit_order: list[int] = []   # slots, oldest first
@@ -247,21 +248,14 @@ class PagedServeLoop(_ServeBase):
         self._chunk_prefill = jax.jit(self._chunk_impl, donate_argnums=(1,))
 
     # -- jitted kernels -------------------------------------------------
-    def _stack(self, x):
-        """Broadcast a per-slot host array across the layer axis (every
-        layer shares one block table / length vector)."""
-        L = self.model.cfg.num_layers
-        return jnp.broadcast_to(x[None], (L,) + x.shape)
-
     def _decode_impl(self, params, pages, bt, tokens, positions):
-        cache = {**pages, "bt": self._stack(bt),
-                 "len": self._stack(positions[:, 0])}
         with self._rules_ctx():
-            logits, cache = self.model.apply(
-                params, {"tokens": tokens, "positions": positions},
-                mode="decode", cache=cache)
+            logits, pages = self.model.apply(
+                params, {"tokens": tokens, "positions": positions,
+                         "block_tables": bt},
+                mode="decode", cache=pages)
         nxt = jnp.argmax(logits[:, -1].astype(jnp.float32), axis=-1)
-        return nxt, {k: cache[k] for k in pages}
+        return nxt, pages
 
     def _chunk_impl(self, params, pages, tokens, positions, bt_row,
                     last_index):

@@ -20,8 +20,7 @@ A CacheSpec is `layout[:shards]/dtype`:
                           and decode merges per-segment softmax partials
                           via log-sum-exp (layers.ring_decode_attention).
                           Always divides (seq lengths are 2^k), so it is
-                          the fallback when head-sharding can't;
-          "paged"      -- the block-pool cache (core/paging.py).
+                          the fallback when head-sharding can't.
   shards  ring only: the static segment count; 0 = the ambient mesh's
           "model" axis size at trace time.
   dtype   "bf16", or "int8" -- rowwise-quantised K/V (kernels/quant8,
@@ -46,7 +45,7 @@ import jax.numpy as jnp
 
 from repro.models.param import pdef
 
-CACHE_LAYOUTS = ("replicated", "head", "ring", "paged")
+CACHE_LAYOUTS = ("replicated", "head", "ring")
 CACHE_DTYPES = ("bf16", "int8")
 
 #: decode headroom appended to non-windowed prefill caches (slots for
@@ -157,24 +156,21 @@ def attention_cache_defs(cfg, batch: int, seq_len: int,
     return d
 
 
-def paged_attention_cache_defs(cfg, batch, num_blocks, block_size,
-                               max_blocks_per_seq):
-    """Abstract paged-cache leaves (per layer): one block POOL shared by
-    ALL sequences plus per-slot block tables and lengths.  Unlike the
-    contiguous cache, HBM scales with the pool (total tokens resident),
-    not max_batch * max_len.  A block is head-major, (Hkv, BS, D): each
-    head's BS rows are whole bf16 tiles, so the pool is stored unpadded
-    and in the order the decode kernel reads it.  The pool is bf16 +
-    head-sharded only (the block dim hosts scatter writes, which GSPMD
-    cannot shard without gathering the pool)."""
+def paged_attention_cache_defs(cfg, num_blocks, block_size):
+    """Abstract paged-cache leaves (per layer): the K/V block POOL shared
+    by ALL sequences, and nothing else -- the block tables and positions
+    arrive with each step.  Unlike the contiguous cache, HBM scales with
+    the pool (total tokens resident), not max_batch * max_len.  A block
+    is head-major, (Hkv, BS, D): each head's BS rows are whole bf16
+    tiles, so the pool is stored unpadded and in the order the decode
+    kernel reads it.  The pool is bf16 + head-sharded only (the block dim
+    hosts scatter writes, which GSPMD cannot shard without gathering the
+    pool)."""
     kv = (num_blocks, cfg.num_kv_heads, block_size, cfg.head_dim)
     ax = (None, "kv_heads", None, None)
     return {
         "kp": pdef(kv, ax, dtype=jnp.bfloat16, init="zeros"),
         "vp": pdef(kv, ax, dtype=jnp.bfloat16, init="zeros"),
-        "bt": pdef((batch, max_blocks_per_seq), ("batch", None),
-                   dtype=jnp.int32, init="zeros"),
-        "len": pdef((batch,), ("batch",), dtype=jnp.int32, init="zeros"),
     }
 
 
@@ -205,17 +201,13 @@ def latent_cache_defs(cfg, batch: int, seq_len: int):
     }
 
 
-def paged_latent_cache_defs(cfg, batch, num_blocks, block_size,
-                            max_blocks_per_seq):
+def paged_latent_cache_defs(cfg, num_blocks, block_size):
     """MLA's paged cache, per layer: a pool of latent rows (NB, BS,
     latent_lanes) shared by all slots -- one row per token, read by every
-    head -- plus per-slot block tables and lengths."""
+    head."""
     return {
         "latent": pdef((num_blocks, block_size, latent_lanes(cfg)),
                        (None, None, None), dtype=jnp.bfloat16, init="zeros"),
-        "bt": pdef((batch, max_blocks_per_seq), ("batch", None),
-                   dtype=jnp.int32, init="zeros"),
-        "len": pdef((batch,), ("batch",), dtype=jnp.int32, init="zeros"),
     }
 
 

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.dist.sharding import constrain
+from repro.models import cache as kvcache
 from repro.models import layers as L
 from repro.models.param import pdef, stack_defs
 
@@ -106,7 +107,7 @@ def _dec_block(lp, cfg, x, positions, enc_out, mode, cache):
 def encdec_cache_defs(cfg, batch: int, seq_len: int):
     el = enc_len_for(seq_len)
     per_layer = {
-        "self": L.attention_cache_defs(cfg, batch, seq_len),
+        "self": kvcache.attention_cache_defs(cfg, batch, seq_len),
         "cross": {
             "k": pdef((batch, el, cfg.num_kv_heads, cfg.head_dim),
                       ("batch", None, "kv_heads", "kv_head_dim"), init="zeros"),

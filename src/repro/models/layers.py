@@ -425,10 +425,6 @@ def paged_chunk_attention(q, k_seq, v_seq, positions):
     return out.reshape(B, C, H, D)
 
 
-# the paged-cache convention lives in models/cache.py with the rest
-paged_attention_cache_defs = kvcache.paged_attention_cache_defs
-
-
 def select_attention(q, k, v, *, causal=True, window=0, q_offset=0):
     """Pick exact vs blockwise path from the (static) sequence length.
 
@@ -473,7 +469,11 @@ def attention_defs(cfg, d_model=None, cross=False):
 def attention_apply(p, cfg, x, positions, *, mode="train", cache=None,
                     kv_source=None, causal=True, window=None,
                     is_cross=False):
-    """mode: train/prefill (full seq) or decode (T==1, uses cache).
+    """mode: train/prefill (full seq), or decode (T==1) / chunk_prefill
+    (a prompt chunk) over a cache.  A paged cache holds the stacked K/V
+    pools ("kp", "vp"), this layer's index ("layer") and the block tables
+    ("bt"); `positions` (B, T) gives where each token is written, -1
+    dropping it.  A contiguous cache holds {k, v, (scales,) len}.
 
     Cross-attention (enc-dec): pass kv_source=enc_out in train/prefill, or
     is_cross=True in decode (cache then holds the STATIC encoder K/V built
@@ -515,18 +515,25 @@ def attention_apply(p, cfg, x, positions, *, mode="train", cache=None,
         kk = rope_apply(kk, positions, cfg.rope_theta, cfg.rope_fraction)
 
     new_cache = cache
-    if mode == "chunk_prefill" and cache is not None and "kp" in cache:
-        # paged chunked prefill: write this chunk's K/V into the layer's
-        # blocks of the stacked pool, then exact attention over the
-        # sequence's gathered view (which already holds any shared-prefix
-        # blocks -- their positions are simply never re-computed).
+    if cache is not None and "kp" in cache:
+        # paged (decode and chunk_prefill): write this step's K/V into the
+        # layer's blocks of the stacked pool, then read the slot's view,
+        # which already holds any shared-prefix blocks (their positions
+        # are simply never re-computed).  The write comes first: a token
+        # attends to itself.
         assert not window, "paged cache does not support sliding windows"
         layer, bt = cache["layer"], cache["bt"]
         with jax.named_scope("paged_gather"):
             kp, vp = paged_kv_write(cache["kp"], cache["vp"], layer, bt,
                                     kk, vv, positions)
-            k_seq, v_seq = paged_gather_kv(kp, vp, layer, bt)
-        out = paged_chunk_attention(q, k_seq, v_seq, positions)
+            if mode != "decode":
+                k_seq, v_seq = paged_gather_kv(kp, vp, layer, bt)
+        if mode == "decode":
+            from repro.kernels.paged_attention import ops as paged_ops
+            out = paged_ops.paged_decode_attention(q, kp, vp, layer, bt,
+                                                   positions[:, 0] + 1)
+        else:
+            out = paged_chunk_attention(q, k_seq, v_seq, positions)
         new_cache = {"kp": kp, "vp": vp}
     elif mode == "chunk_prefill":
         # CONTIGUOUS chunked prefill (rectangular batch: all rows at the
@@ -545,17 +552,6 @@ def attention_apply(p, cfg, x, positions, *, mode="train", cache=None,
         k_read, v_read = kvcache.read_kv(new_cache)
         out = select_attention(q, k_read, v_read, causal=True,
                                window=window, q_offset=cache_len[0])
-    elif mode == "decode" and "kp" in cache:
-        assert not window, "paged cache does not support sliding windows"
-        layer, bt, cache_len = cache["layer"], cache["bt"], cache["len"]
-        with jax.named_scope("paged_gather"):
-            kp, vp = paged_kv_write(cache["kp"], cache["vp"], layer, bt,
-                                    kk, vv, cache_len[:, None])
-        # the write comes first: the new token attends to itself
-        from repro.kernels.paged_attention import ops as paged_ops
-        out = paged_ops.paged_decode_attention(q, kp, vp, layer, bt,
-                                               cache_len + 1)
-        new_cache = {"kp": kp, "vp": vp}
     elif mode == "decode":
         spec = kvcache.spec_of(cfg)
         cache_len = cache["len"]
@@ -589,11 +585,6 @@ def attention_apply(p, cfg, x, positions, *, mode="train", cache=None,
     out = constrain(out, q_axes if seq_cp else ("batch", None, "heads", None))
     y = jnp.einsum("bthk,hkd->btd", out, p["wo"])
     return constrain(y, ("batch", None, None)), new_cache
-
-
-# the contiguous-cache convention (shapes / dtypes / logical axes per
-# CacheSpec) lives in models/cache.py
-attention_cache_defs = kvcache.attention_cache_defs
 
 
 # --------------------------------------------------------------------------
@@ -668,9 +659,9 @@ def latent_attention(q, rows, positions, *, scale, dv):
 def mla_apply(p, cfg, x, positions, *, mode="train", cache=None):
     """MLA block: expanded in train / prefill; absorbed over the latent
     pool in chunk_prefill and decode, where `cache` holds the stacked
-    pool ("latent"), this layer's index ("layer"), the block tables
-    ("bt") and, in decode, the lengths ("len").  Returns (out, {"latent":
-    pool} or None)."""
+    pool ("latent"), this layer's index ("layer") and the block tables
+    ("bt"), and `positions` (B, T) gives where each row is written, -1
+    dropping it.  Returns (out, {"latent": pool} or None)."""
     B, T, _ = x.shape
     H, r = cfg.num_heads, cfg.kv_lora_rank
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
@@ -696,10 +687,10 @@ def mla_apply(p, cfg, x, positions, *, mode="train", cache=None):
         W = pool.shape[-1]
         pad = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 1)
                                 + [(0, W - a.shape[-1])])
-        wpos = cache["len"][:, None] if mode == "decode" else positions
         with jax.named_scope("latent_write"):
             new_pool = latent_write(pool, layer, bt,
-                                    pad(jnp.concatenate([c, k_pe], -1)), wpos)
+                                    pad(jnp.concatenate([c, k_pe], -1)),
+                                    positions)
         with jax.named_scope("mla_absorb"):
             q_lat = jnp.einsum("bthk,rhk->bthr", q_nope, p["w_uk"])
             q_row = pad(jnp.concatenate([q_lat, q_pe.astype(q_lat.dtype)],
@@ -708,7 +699,7 @@ def mla_apply(p, cfg, x, positions, *, mode="train", cache=None):
             # the write comes first: the new token attends to itself
             from repro.kernels.paged_attention import ops as paged_ops
             o_lat = paged_ops.paged_latent_attention(
-                q_row[:, 0], new_pool, layer, bt, cache["len"] + 1,
+                q_row[:, 0], new_pool, layer, bt, positions[:, 0] + 1,
                 scale=scale, dv=r)[:, None]
         else:
             o_lat = latent_attention(q_row, latent_gather(new_pool, layer, bt),

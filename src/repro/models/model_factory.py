@@ -46,10 +46,10 @@ class Model:
     # ---- caches ----
     @property
     def supports_cache_spec(self) -> bool:
-        """CacheSpec layouts (ring / int8) apply to growing KV caches;
-        SSM / RG-LRU state, the enc-dec cross cache and MLA's latent rows
-        keep their own conventions."""
-        return self.cfg.family in ("dense", "moe", "vlm") and not self.cfg.mla
+        """CacheSpec layouts (ring / int8) apply to the transformer's
+        growing K/V cache; SSM / RG-LRU state, the enc-dec cross cache and
+        MLA's latent rows keep their own conventions."""
+        return self._cache_defs is transformer.cache_defs and not self.cfg.mla
 
     def cache_defs(self, batch: int, seq_len: int, spec=None):
         """Decode-cache defs; `spec` (a models/cache.CacheSpec or its
@@ -71,21 +71,19 @@ class Model:
         static and a sliding window keeps a ring, so those keep the
         contiguous path."""
         try:
-            self.paged_cache_defs(1, 1, 1, 1)
+            self.paged_cache_defs(1, 1)
         except ValueError:
             return False
         return True
 
-    def paged_cache_defs(self, batch: int, num_blocks: int, block_size: int,
-                         max_blocks_per_seq: int):
-        """Raises ValueError, with the reason, for a cache that cannot be
-        paged."""
+    def paged_cache_defs(self, num_blocks: int, block_size: int):
+        """The stacked block pool; raises ValueError, with the reason, for
+        a cache that cannot be paged."""
         if self._cache_defs is not transformer.cache_defs:
             raise ValueError(f"{self.cfg.name}: paged KV cache unsupported "
                              f"(family={self.cfg.family}: its decode cache "
                              "does not grow a row per token)")
-        return transformer.paged_cache_defs(
-            self.cfg, batch, num_blocks, block_size, max_blocks_per_seq)
+        return transformer.paged_cache_defs(self.cfg, num_blocks, block_size)
 
     # ---- inputs ----
     def input_defs(self, shape: ShapeConfig):

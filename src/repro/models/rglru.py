@@ -12,6 +12,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.dist.sharding import constrain
+from repro.models import cache as kvcache
 from repro.models import layers as L
 from repro.models.param import pdef, stack_defs
 from repro.models.ssm import _causal_conv, _chunked_linear_scan
@@ -142,7 +143,7 @@ def hybrid_cache_defs(cfg, batch: int, seq_len: int):
     per_super = {
         "rec0": _rec_cache_defs(cfg, batch),
         "rec1": _rec_cache_defs(cfg, batch),
-        "attn": L.attention_cache_defs(cfg, batch, seq_len),
+        "attn": kvcache.attention_cache_defs(cfg, batch, seq_len),
     }
     defs = {"super": stack_defs(per_super, n_super)}
     for i in range(n_tail):

@@ -1,9 +1,11 @@
-"""Decoder-only LM (dense + MoE + VLM-stub), scan-over-layers, 3 modes.
+"""Decoder-only LM (dense + MoE + VLM-stub), scan-over-layers.
 
 Modes:
-  train   -- full-sequence forward, returns (logits, aux)
-  prefill -- full-sequence forward, returns (logits, cache)
-  decode  -- single-token step with KV cache, returns (logits, cache)
+  train         -- full-sequence forward, returns (logits, aux)
+  prefill       -- full-sequence forward, returns (logits, cache)
+  decode        -- single-token step with a cache, returns (logits, cache)
+  chunk_prefill -- a prompt chunk written into a cache, returns the last
+                   valid position's (logits, cache)
 
 Named scopes (`jax.named_scope`) mark each part's operations in the
 compiled program's metadata, where a profiler trace finds them: embed,
@@ -12,14 +14,17 @@ and mla_absorb for latent attention), mlp / moe (router, routed_experts,
 shared_experts) and head.  They are metadata only and leave the compiled
 code as it was.
 
-On the paged paths (decode, and chunk_prefill with block tables) the
-stacked block pool rides the layer scans as a CARRY: the K/V pools
-{kp, vp} of (L, NB, Hkv, BS, D), or a latent-attention (MLA) model's
-latent pool (L, NB, BS, W).  Each layer writes and reads its own slice
-in place, by its index, so the pool is never sliced per layer or
-re-stacked, and a leading stack of dense layers and the stack after it
-share it.  The contiguous cache (ServeLoop, the chunked prefill step)
-still rides the scan as xs, sliced and re-stacked per layer.
+A decode or chunk_prefill batch that holds `block_tables` (B, nbmax) runs
+the PAGED path: `cache` is the stacked block pool as `paged_cache_defs`
+gives it -- the K/V pools {kp, vp} of (L, NB, Hkv, BS, D), or a
+latent-attention (MLA) model's latent pool (L, NB, BS, W) -- and the
+step returns the pool.  The tables and the batch's `positions` are step
+inputs, shared by every layer.  The pool rides the layer scans as a
+CARRY: each layer writes and reads its own slice in place, by its index,
+so the pool is never sliced per layer or re-stacked, and a leading stack
+of dense layers and the stack after it share it.  Without block tables
+the contiguous cache (ServeLoop) rides the scan as xs, sliced and
+re-stacked per layer.
 """
 from __future__ import annotations
 
@@ -69,23 +74,21 @@ def cache_defs(cfg, batch: int, seq_len: int, spec=None):
     if cfg.mla:
         per_layer = kvcache.latent_cache_defs(cfg, batch, seq_len)
     else:
-        per_layer = L.attention_cache_defs(cfg, batch, seq_len, spec)
+        per_layer = kvcache.attention_cache_defs(cfg, batch, seq_len, spec)
     return stack_defs(per_layer, cfg.num_layers)
 
 
-def paged_cache_defs(cfg, batch: int, num_blocks: int, block_size: int,
-                     max_blocks_per_seq: int):
-    """Block-table paged decode cache (see core/paging.py): one KV block
-    pool per layer (a latent pool for MLA), shared by all slots, plus
-    per-slot tables/lengths."""
+def paged_cache_defs(cfg, num_blocks: int, block_size: int):
+    """Block-table paged decode cache (see core/paging.py): one block
+    pool per layer, a K/V pool or, for MLA, a latent pool, shared by all
+    slots and stacked (L, NB, ...)."""
     if cfg.window:
         raise ValueError(
             f"{cfg.name}: sliding-window attention (window={cfg.window}) "
             "keeps a ring of the last positions; the paged pool does not")
     fn = (kvcache.paged_latent_cache_defs if cfg.mla
-          else L.paged_attention_cache_defs)
-    per_layer = fn(cfg, batch, num_blocks, block_size, max_blocks_per_seq)
-    return stack_defs(per_layer, cfg.num_layers)
+          else kvcache.paged_attention_cache_defs)
+    return stack_defs(fn(cfg, num_blocks, block_size), cfg.num_layers)
 
 
 def _block_apply(p, cfg, x, positions, mode, cache):
@@ -119,18 +122,19 @@ def lm_apply(params, cfg, batch_inputs, *, mode="train", cache=None):
     with jax.named_scope("embed"):
         x = _embed_inputs(params, cfg, batch_inputs)
     B, T = x.shape[0], x.shape[1]
-    if mode == "decode":
-        # cache["len"] is stacked (L, B); all layers share the same length.
-        positions = batch_inputs.get("positions", cache["len"][0].reshape(B, 1))
-    elif mode == "chunk_prefill":
-        # absolute positions of this chunk's tokens; -1 marks padding rows
-        # (bucketed tail chunks) whose cache writes and logits are dropped.
+    if mode in ("train", "prefill"):
+        positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
+    elif "positions" in batch_inputs:
+        # absolute positions of the step's tokens; -1 marks padding rows
+        # (bucketed tail chunks, free decode slots) whose cache writes and
+        # logits are dropped.
         positions = batch_inputs["positions"]
     else:
-        positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
-    bt = batch_inputs.get("block_tables")  # (B, nbmax), chunk_prefill only
-    if (mode == "decode" and "bt" not in cache) or (
-            mode == "chunk_prefill" and bt is None):
+        # a contiguous decode cache's "len" is stacked (L, B); all layers
+        # share the same length.
+        positions = cache["len"][0].reshape(B, 1)
+    bt = batch_inputs.get("block_tables")
+    if bt is None and mode in ("decode", "chunk_prefill"):
         x, aux, new_cache = _contiguous_layers(params, cfg, x, positions,
                                                mode, cache)
     else:
@@ -139,19 +143,19 @@ def lm_apply(params, cfg, batch_inputs, *, mode="train", cache=None):
     return _head(params, cfg, batch_inputs, x, mode, aux, new_cache)
 
 
-def _layers(params, cfg, x, positions, mode, cache, bt):
-    """The dense then the uniform stack.  On the paged paths the stacked
-    pools ({kp, vp} or {latent}, as the defs give them) are carried
-    through both, each layer indexing its own slice; in train / prefill
-    the per-layer caches (prefill's K/V, or None) are stacked as the scan
-    returns them.  Returns (x, aux, new cache)."""
-    paged = mode in ("decode", "chunk_prefill")
-    pool = ({k: v for k, v in cache.items() if k not in ("bt", "len")}
-            if paged else None)
-    lens = None
-    if mode == "decode":
-        # tables and lengths arrive stacked (L, B, ...); all layers share them
-        bt, lens = cache["bt"][0], cache["len"][0]
+def _layers(params, cfg, x, positions, mode, pool, bt):
+    """The dense then the uniform stack.  On the paged path (block tables
+    `bt` given) the stacked `pool` ({kp, vp} or {latent}, as the defs give
+    it) is carried through both, each layer indexing its own slice; in
+    train / prefill the per-layer caches (prefill's K/V, or None) are
+    stacked as the scan returns them.  Returns (x, aux, new cache)."""
+    paged = bt is not None
+    if not paged:
+        pool = None
+    # a decode step has one position a slot: the scans close over them as
+    # a (B,) vector, which XLA keeps in 128-lane tiles; a (B, 1) one would
+    # be padded to (1, 128) tiles, 128 times its size
+    flat = positions[:, 0] if mode == "decode" else positions
     aux, first, stacks = 0.0, 0, []
     for name in ("dense_layers", "layers"):
         if name not in params:
@@ -160,12 +164,9 @@ def _layers(params, cfg, x, positions, mode, cache, bt):
         def body(carry, xs):
             x, aux, pool = carry
             lp, i = xs
-            lc = None
-            if paged:
-                lc = {**pool, "layer": i, "bt": bt}
-                if lens is not None:
-                    lc["len"] = lens
-            x, new, a = _block_apply(lp, cfg, x, positions, mode, lc)
+            lc = {**pool, "layer": i, "bt": bt} if paged else None
+            pos = flat[:, None] if mode == "decode" else flat
+            x, new, a = _block_apply(lp, cfg, x, pos, mode, lc)
             if paged:
                 return (x, aux + a, new), None
             return (x, aux + a, pool), new
@@ -178,8 +179,6 @@ def _layers(params, cfg, x, positions, mode, cache, bt):
             (params[name], first + jnp.arange(n, dtype=jnp.int32)))
         stacks.append(ys)
         first += n
-    if mode == "decode":
-        return x, aux, {**pool, "bt": cache["bt"], "len": cache["len"] + 1}
     if paged:
         return x, aux, pool
     if len(stacks) == 1:

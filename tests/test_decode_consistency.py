@@ -83,14 +83,12 @@ def test_paged_chunk_prefill_then_decode_matches_teacher_forcing(arch, dense):
     params = model.init(jax.random.key(7))
     B, T, extra = 1, 13, 3
     bs, chunk, num_blocks = 4, 8, 8
-    L = cfg.num_layers
     full = _batch(model, T + extra, B, seed=7)
     ref_logits, _ = model.apply(params, full, mode="train")
 
-    defs = model.paged_cache_defs(B, num_blocks, bs, num_blocks)
-    zeros = jax.tree.map(lambda d: jnp.zeros(d.shape, d.dtype), defs,
+    pages = jax.tree.map(lambda d: jnp.zeros(d.shape, d.dtype),
+                         model.paged_cache_defs(num_blocks, bs),
                          is_leaf=is_def)
-    pages = {"kp": zeros["kp"], "vp": zeros["vp"]}
     # identity block table: position p lives in block p // bs
     bt = jnp.arange(num_blocks, dtype=jnp.int32)[None]          # (1, nb)
 
@@ -115,15 +113,12 @@ def test_paged_chunk_prefill_then_decode_matches_teacher_forcing(arch, dense):
         np.asarray(ref_logits[:, T - 1], np.float32),
         rtol=2e-2, atol=2e-2)
 
-    stack = lambda x: jnp.broadcast_to(x[None], (L,) + x.shape)
     for i in range(extra):
-        cache = {"kp": pages["kp"], "vp": pages["vp"], "bt": stack(bt),
-                 "len": stack(jnp.full((B,), T + i, jnp.int32))}
         dec_in = {"tokens": full["tokens"][:, T + i: T + i + 1],
-                  "positions": jnp.full((B, 1), T + i, jnp.int32)}
-        logits, cache = model.apply(params, dec_in, mode="decode",
-                                    cache=cache)
-        pages = {"kp": cache["kp"], "vp": cache["vp"]}
+                  "positions": jnp.full((B, 1), T + i, jnp.int32),
+                  "block_tables": bt}
+        logits, pages = model.apply(params, dec_in, mode="decode",
+                                    cache=pages)
         np.testing.assert_allclose(
             np.asarray(logits[:, 0], np.float32),
             np.asarray(ref_logits[:, T + i], np.float32),

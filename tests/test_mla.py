@@ -100,9 +100,9 @@ def test_paged_paths_match_reference(smoke, impl, monkeypatch):
     chunk_step, decode_step = (jax.jit(functools.partial(
         lambda p, b, c, mode: model.apply(p, b, mode=mode, cache=c),
         mode=m)) for m in ("chunk_prefill", "decode"))
-    bs, nb, nbmax = 8, 16, 6
+    bs, nb = 8, 16
     pool = {"latent": jnp.zeros(model.paged_cache_defs(
-        1, nb, bs, nbmax)["latent"].shape, jnp.bfloat16)}
+        nb, bs)["latent"].shape, jnp.bfloat16)}
     bt = jnp.asarray([[5, 2, 9, 12, 7, 3]], jnp.int32)
     T, chunk = 27, 16
     out = {}
@@ -116,15 +116,10 @@ def test_paged_paths_match_reference(smoke, impl, monkeypatch):
             "tokens": jnp.asarray(tk), "positions": jnp.asarray(pos),
             "block_tables": bt, "last_index": jnp.asarray([c - 1])}, pool)
         out[start + c - 1] = lg[0, 0]
-    Lyr = CFG.num_layers
     for t in range(T, len(toks) - 1):
-        cache = {"latent": pool["latent"],
-                 "bt": jnp.broadcast_to(bt[None], (Lyr,) + bt.shape),
-                 "len": jnp.full((Lyr, 1), t, jnp.int32)}
-        lg, cache = decode_step(params, {
+        lg, pool = decode_step(params, {
             "tokens": jnp.asarray([[toks[t]]]),
-            "positions": jnp.asarray([[t]])}, cache)
-        pool = {"latent": cache["latent"]}
+            "positions": jnp.asarray([[t]]), "block_tables": bt}, pool)
         out[t] = lg[0, 0]
     rows = sorted(out)
     want = ref_logits(params, CFG, toks, rows)
